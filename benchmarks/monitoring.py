@@ -99,6 +99,7 @@ def bench_data_volume(out_dir: Path):
     """Measure bytes per node per sample; extrapolate fleet volume."""
     import tempfile
     from repro.core.daemon import DaemonConfig, Hpcmd, JobManifest
+    from repro.core.derived import TPU_V5E
     from repro.core.sources import (DeviceSource, EnvSource, ProcSource,
                                     StaticStepCost, StepClock,
                                     XlaCostSource)
@@ -107,7 +108,7 @@ def bench_data_volume(out_dir: Path):
     d = Hpcmd(tmp / "spool", DaemonConfig(align_to_clock=False),
               host="bench-node", manifest=JobManifest(job_id="bench.1",
                                                       app="gemma2-27b"))
-    src = XlaCostSource(clock)
+    src = XlaCostSource(clock, TPU_V5E)  # a simulated v5e node
     src.set_cost(StaticStepCost(flops=1e12, bytes=1e11,
                                 collective_bytes=1e9, num_chips=4,
                                 tokens_per_step=4096))
@@ -184,14 +185,15 @@ def bench_overhead(out_dir: Path):
 def bench_roofline_view(out_dir: Path):
     """Fig. 2: roofline overview of a fleet."""
     from repro.core.dashboards import render_roofline_svg, roofline_points
+    from repro.core.derived import TPU_V5E
     store, manifests, _ = _fleet_store()
     points = roofline_points(store, manifests)
-    svg = render_roofline_svg(points)
+    svg = render_roofline_svg(points, TPU_V5E)
     out = out_dir / "dashboards"
     out.mkdir(parents=True, exist_ok=True)
     (out / "roofline.svg").write_text(svg)
     us = timeit(lambda: render_roofline_svg(
-        roofline_points(store, manifests)))
+        roofline_points(store, manifests), TPU_V5E))
     return [row("roofline_view.render", us,
                 f"{len(points)}jobs->{out / 'roofline.svg'}")]
 

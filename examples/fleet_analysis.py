@@ -24,6 +24,7 @@ from repro.core.dashboards import (markdown_table, render_roofline_svg,
                                    view_low_participation,
                                    view_memory_underuse,
                                    view_top_apps_by_device_hours)
+from repro.core.derived import TPU_V5E
 from repro.core.detectors import DetectorBank
 from repro.core.report import generate_report
 from repro.core.sources import StaticStepCost, StepClock, XlaCostSource
@@ -58,7 +59,7 @@ def simulate_fleet(root: Path, n_islands=2, jobs_per_island=4,
                 clock = StepClock()
                 d = Hpcmd(spool, DaemonConfig(align_to_clock=False),
                           host=host, manifest=man)
-                src = XlaCostSource(clock)
+                src = XlaCostSource(clock, TPU_V5E)  # simulated v5e
                 src.set_cost(StaticStepCost(
                     flops=flops, bytes=flops / rng.uniform(2, 200),
                     collective_bytes=flops / 500, num_chips=4,
@@ -113,7 +114,7 @@ def main() -> None:
 
     # --- Fig 2: roofline overview ---------------------------------------
     points = roofline_points(agg.store, manifests)
-    svg = render_roofline_svg(points)
+    svg = render_roofline_svg(points, TPU_V5E)
     (root / "roofline.svg").write_text(svg)
     print(f"roofline overview: {root / 'roofline.svg'} "
           f"({len(points)} jobs)\n")
@@ -148,7 +149,7 @@ def main() -> None:
     if events:
         job = events[0].job
         report = generate_report(agg.store, job, root / "reports" / job,
-                                 manifests)
+                                 manifests, hw=TPU_V5E)
         print(f"\nper-job report for {job}: {report}")
 
 

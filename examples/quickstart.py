@@ -47,7 +47,8 @@ def main() -> None:
     figures = monitor.register_compiled(compiled, tokens_per_step=4 * 64)
     print(f"compiled step: {figures['flops']:.2e} FLOPs/step, "
           f"{figures['collective_bytes']:.2e} collective B/step, "
-          f"dominant roofline term: {figures['dominant']}")
+          f"dominant roofline term: "
+          f"{figures.get('dominant', 'none (no peaks for this device)')}")
 
     for i in range(30):
         batch = {k: jnp.asarray(v) for k, v in pipe.next().items()}

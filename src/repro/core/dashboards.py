@@ -40,7 +40,7 @@ import numpy as np
 
 from repro.core.aggregator import MetricStore
 from repro.core.daemon import JobManifest
-from repro.core.derived import HardwareSpec, TPU_V5E
+from repro.core.derived import HardwareSpec
 from repro.core.shards import ShardedAggregator
 from repro.core.splunklite import QueryHandle, query
 
@@ -151,8 +151,7 @@ def roofline_points(store: StoreLike,
     return points
 
 
-def render_roofline_svg(points: Sequence[JobPoint],
-                        hw: HardwareSpec = TPU_V5E,
+def render_roofline_svg(points: Sequence[JobPoint], hw: HardwareSpec,
                         width: int = 860, height: int = 560,
                         title: str = "Job roofline overview") -> str:
     """Fig. 2 analog: log-log roofline with one circle per job."""

@@ -15,13 +15,13 @@ from typing import Dict, Optional
 
 @dataclass(frozen=True)
 class HardwareSpec:
-    """Per-chip peaks for the target part (defaults: TPU v5e)."""
+    """Published per-chip peaks of one accelerator part."""
 
-    name: str = "tpu-v5e"
-    peak_flops: float = 197e12       # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9            # bytes/s per chip
-    ici_bw: float = 50e9             # bytes/s per ICI link
-    hbm_bytes: float = 16e9          # HBM capacity per chip
+    name: str
+    peak_flops: float                # bf16 FLOP/s per chip
+    hbm_bw: float                    # bytes/s per chip
+    ici_bw: float                    # bytes/s per ICI link
+    hbm_bytes: float                 # HBM capacity per chip
 
     @property
     def ridge_ai(self) -> float:
@@ -33,7 +33,35 @@ class HardwareSpec:
         return min(self.peak_flops, ai * self.hbm_bw)
 
 
-TPU_V5E = HardwareSpec()
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+# at 819 GB/s, 1,600 Gbit/s of ICI per chip over four links.
+TPU_V5E = HardwareSpec(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
+                       ici_bw=50e9, hbm_bytes=16e9)
+
+# Keyed by ``jax.Device.device_kind``, as the runtime names the part.
+HARDWARE: Dict[str, HardwareSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(platform: str, device_kind: str) -> Optional[HardwareSpec]:
+    """Peaks of a device as JAX reports it.
+
+    The host CPU has no entry and gets ``None``: no utilization or
+    roofline figure is computed against it.  An accelerator missing from
+    :data:`HARDWARE` raises rather than borrowing another part's peaks.
+    """
+    if platform == "cpu":
+        return None
+    if device_kind not in HARDWARE:
+        raise KeyError(f"no published peaks for {platform} device kind "
+                       f"{device_kind!r}; add them to derived.HARDWARE")
+    return HARDWARE[device_kind]
+
+
+def local_hardware() -> Optional[HardwareSpec]:
+    """Peaks of the first device of this process (see hardware_for)."""
+    import jax
+    dev = jax.devices()[0]
+    return hardware_for(dev.platform, dev.device_kind)
 
 
 @dataclass(frozen=True)
@@ -68,7 +96,7 @@ class RooflineTerms:
 
 def roofline_terms(hlo_flops: float, hlo_bytes: float,
                    collective_bytes: float, num_chips: int,
-                   hw: HardwareSpec = TPU_V5E) -> RooflineTerms:
+                   hw: HardwareSpec) -> RooflineTerms:
     """Three-term roofline from whole-program figures.
 
     ``hlo_flops``/``hlo_bytes`` are whole-step totals over all chips
@@ -103,7 +131,7 @@ def arithmetic_intensity(flops: float, bytes_moved: float) -> float:
 
 
 def mfu(flops_per_step: float, step_time_s: float, num_chips: int,
-        hw: HardwareSpec = TPU_V5E) -> float:
+        hw: HardwareSpec) -> float:
     """Model-FLOPs utilization in [0,1]."""
     if step_time_s <= 0 or num_chips <= 0:
         return 0.0
@@ -125,15 +153,19 @@ def useful_flops_ratio(model_flops: float, hlo_flops: float) -> float:
 
 def perf_fields(flops_per_step: float, bytes_per_step: float,
                 collective_bytes_per_step: float, step_time_s: float,
-                num_chips: int, hw: HardwareSpec = TPU_V5E) -> Dict[str, float]:
-    """The standard derived-metric bundle hpcmd emits per perf sample."""
+                num_chips: int, hw: Optional[HardwareSpec]
+                ) -> Dict[str, float]:
+    """The standard derived-metric bundle hpcmd emits per perf sample
+    (``mfu`` only where the part's peak is known)."""
     gfl = achieved_gflops(flops_per_step, step_time_s)
-    return {
+    out = {
         "gflops": gfl,
         "gflops_per_chip": gfl / max(num_chips, 1),
         "hbm_gbs": achieved_gbs(bytes_per_step, step_time_s),
         "ici_gbs": achieved_gbs(collective_bytes_per_step, step_time_s),
         "ai": arithmetic_intensity(flops_per_step, bytes_per_step),
-        "mfu": mfu(flops_per_step, step_time_s, num_chips, hw),
         "step_time_s": step_time_s,
     }
+    if hw is not None:
+        out["mfu"] = mfu(flops_per_step, step_time_s, num_chips, hw)
+    return out

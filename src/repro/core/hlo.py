@@ -157,8 +157,6 @@ def cost_figures(compiled) -> Dict[str, float]:
     XLA:CPU/TPU report per-partition figures on the partitioned module.
     """
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returned [dict]
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     return {"flops": max(flops, 0.0), "bytes": max(byts, 0.0)}

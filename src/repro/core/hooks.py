@@ -17,7 +17,7 @@ from typing import Dict, Optional
 
 from repro.core import hlo_cost
 from repro.core.daemon import DaemonConfig, Hpcmd, JobManifest
-from repro.core.derived import HardwareSpec, TPU_V5E, roofline_terms
+from repro.core.derived import HardwareSpec, local_hardware, roofline_terms
 from repro.core.sources import (CollectiveSource, DeviceSource, EnvSource,
                                 PipelineSource, PipelineStats, ProcSource,
                                 StaticStepCost, StepClock, XlaCostSource)
@@ -33,12 +33,14 @@ class TrainMonitor:
 
     def __init__(self, workdir: os.PathLike, manifest: JobManifest,
                  host: Optional[str] = None, interval_s: float = 5.0,
-                 hw: HardwareSpec = TPU_V5E, enabled: bool = True,
+                 hw: Optional[HardwareSpec] = None, enabled: bool = True,
                  align_to_clock: bool = True) -> None:
         self.enabled = enabled
         self.workdir = Path(workdir)
         self.manifest = manifest
-        self.hw = hw
+        # peaks of the device this process runs on unless given; None on
+        # the CPU, which then gets no mfu or roofline figures
+        self.hw = hw if hw is not None else local_hardware()
         self.clock = StepClock()
         self.pipeline_stats = PipelineStats()
         host = host or "host0"
@@ -46,7 +48,7 @@ class TrainMonitor:
         cfg = DaemonConfig(interval_s=interval_s,
                            align_to_clock=align_to_clock)
         self.daemon = Hpcmd(spool_dir, cfg, host=host, manifest=manifest)
-        self.cost_source = XlaCostSource(self.clock, hw)
+        self.cost_source = XlaCostSource(self.clock, self.hw)
         self.daemon.add_source(self.cost_source)
         self.daemon.add_source(DeviceSource())
         self.daemon.add_source(ProcSource())
@@ -67,16 +69,13 @@ class TrainMonitor:
                           num_chips: Optional[int] = None) -> Dict[str, float]:
         """Extract static per-step cost figures from a compiled step.
 
-        Returns the figure dict (also used by the dry-run roofline path).
+        Returns the figure dict, with the roofline terms where the
+        device's peaks are known.
         """
         chips = num_chips or self.manifest.num_chips
-        try:
-            text = compiled.as_text()
-        except Exception:  # noqa: BLE001 — some backends can't re-serialize
-            text = ""
         # loop-aware static analysis (core/hlo_cost.py): exact per-step
         # FLOPs / HBM traffic / collective bytes off the executable.
-        cost = hlo_cost.analyze_hlo(text)
+        cost = hlo_cost.analyze_hlo(compiled.as_text())
         static = StaticStepCost(
             flops=cost.flops, bytes=cost.traffic_bytes,
             collective_bytes=cost.collective_bytes,
@@ -85,14 +84,14 @@ class TrainMonitor:
         self.cost_source.set_cost(static)
         if self.enabled:
             self.daemon.add_source(CollectiveSource(cost.as_fields()))
-        terms = roofline_terms(cost.flops * chips,
-                               cost.traffic_bytes * chips,
-                               cost.collective_bytes * chips,
-                               chips, self.hw)
-        self.roofline = terms.as_dict()
-        return {"flops": cost.flops, "bytes": cost.traffic_bytes,
-                "collective_bytes": cost.collective_bytes,
-                **terms.as_dict()}
+        figures = {"flops": cost.flops, "bytes": cost.traffic_bytes,
+                   "collective_bytes": cost.collective_bytes}
+        if self.hw is not None:
+            self.roofline = roofline_terms(
+                cost.flops * chips, cost.traffic_bytes * chips,
+                cost.collective_bytes * chips, chips, self.hw).as_dict()
+            figures.update(self.roofline)
+        return figures
 
     def set_static_cost(self, cost: StaticStepCost) -> None:
         """Direct injection (multi-host simulation / tests)."""

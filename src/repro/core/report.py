@@ -24,7 +24,7 @@ from repro.core.dashboards import (JOB_VIEW_METRICS, JobPoint,
                                    job_metric_series, job_statistical_view,
                                    markdown_table, render_roofline_svg,
                                    render_timeseries_svg, roofline_points)
-from repro.core.derived import HardwareSpec, TPU_V5E
+from repro.core.derived import HardwareSpec, hardware_for
 from repro.core.detectors import DetectorBank
 from repro.core.splunklite import query
 
@@ -37,9 +37,21 @@ def _fmt(v, nd=3):
     return str(v)
 
 
+def job_hardware(store: MetricStore, job: str) -> Optional[HardwareSpec]:
+    """Peaks of the part ``job`` ran on, from the device its meta record
+    names; None where it names none or a CPU."""
+    for r in query(store, f"search kind=meta job={job} "
+                          "| fields backend device_kind"):
+        if r.get("backend") and r.get("device_kind"):
+            return hardware_for(str(r["backend"]), str(r["device_kind"]))
+    return None
+
+
 def job_summary(store: MetricStore, job: str,
                 manifest: Optional[JobManifest] = None,
-                hw: HardwareSpec = TPU_V5E) -> Dict[str, object]:
+                hw: Optional[HardwareSpec] = None) -> Dict[str, object]:
+    """Per-job figures; the roofline placement only where ``hw`` (the
+    part's peaks) is given."""
     rows = query(store, f"search kind=perf job={job} gflops>0 "
                         "| stats avg(gflops) max(gflops) avg(gflops_per_chip) "
                         "avg(hbm_gbs) avg(ici_gbs) avg(ai) avg(mfu) "
@@ -69,7 +81,7 @@ def job_summary(store: MetricStore, job: str,
         "avg_tokens_per_s": float(s.get("avg_tokens_per_s", 0) or 0),
     }
     ai = out["avg_ai"]
-    if ai > 0:
+    if ai > 0 and hw is not None:
         attain = hw.attainable_flops(ai) / 1e9
         out["roofline_attainable_gflops_per_chip"] = attain
         out["roofline_fraction"] = (out["avg_gflops_per_chip"] / attain
@@ -81,12 +93,16 @@ def job_summary(store: MetricStore, job: str,
 
 def generate_report(store: MetricStore, job: str, out_dir: os.PathLike,
                     manifests: Optional[Dict[str, JobManifest]] = None,
-                    hw: HardwareSpec = TPU_V5E) -> Path:
-    """Write ``report.md``, ``report.html`` and SVGs; returns the md path."""
+                    hw: Optional[HardwareSpec] = None) -> Path:
+    """Write ``report.md``, ``report.html`` and SVGs; returns the md path.
+
+    ``hw`` defaults to the part the job's meta record names
+    (:func:`job_hardware`); without one the roofline is left out."""
     manifests = manifests or {}
     out = Path(out_dir)
     out.mkdir(parents=True, exist_ok=True)
     man = manifests.get(job)
+    hw = hw if hw is not None else job_hardware(store, job)
     summ = job_summary(store, job, man, hw)
 
     svgs: List[str] = []
@@ -101,7 +117,7 @@ def generate_report(store: MetricStore, job: str, out_dir: os.PathLike,
                                if k not in ("job", "app", "user")}]))
 
     # roofline placement of THIS job among all jobs in the store
-    points = roofline_points(store, manifests)
+    points = roofline_points(store, manifests) if hw is not None else []
     if points:
         svg = render_roofline_svg(
             points, hw, title=f"Roofline placement — {job}")

@@ -23,7 +23,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 from repro.core import derived
-from repro.core.derived import HardwareSpec, TPU_V5E
+from repro.core.derived import HardwareSpec
 
 Fields = Dict[str, object]
 
@@ -134,7 +134,8 @@ class XlaCostSource(MetricSource):
     name = "xla_cost"
     kind = "perf"
 
-    def __init__(self, clock: StepClock, hw: HardwareSpec = TPU_V5E) -> None:
+    def __init__(self, clock: StepClock,
+                 hw: Optional[HardwareSpec]) -> None:
         self.clock = clock
         self.hw = hw
         self.cost = StaticStepCost()
@@ -152,11 +153,13 @@ class XlaCostSource(MetricSource):
         if dstep <= 0 or dt <= 0:
             # no forward progress in this window — still emit, the hang
             # detector keys off exactly this case
-            return {"step": latest.step, "steps_per_s": 0.0,
-                    "tokens_per_s": 0.0, "loss": latest.loss,
-                    "gflops": 0.0, "gflops_per_chip": 0.0, "hbm_gbs": 0.0,
-                    "ici_gbs": 0.0, "mfu": 0.0, "ai": 0.0,
-                    "step_time_s": 0.0}
+            fields = {"step": latest.step, "steps_per_s": 0.0,
+                      "tokens_per_s": 0.0, "loss": latest.loss,
+                      "gflops": 0.0, "gflops_per_chip": 0.0, "hbm_gbs": 0.0,
+                      "ici_gbs": 0.0, "ai": 0.0, "step_time_s": 0.0}
+            if self.hw is not None:
+                fields["mfu"] = 0.0
+            return fields
         step_time = dt / dstep
         c = self.cost
         fields = derived.perf_fields(
@@ -337,6 +340,7 @@ class EnvSource(MetricSource):
             import jax
             fields["jax_version"] = jax.__version__
             fields["backend"] = jax.default_backend()
+            fields["device_kind"] = jax.devices()[0].device_kind
             fields["device_count"] = jax.device_count()
         except Exception:  # noqa: BLE001
             pass

@@ -9,8 +9,11 @@ iterated last grid axis; running max/normalizer live in VMEM scratch
 [block_q, d] × [d, block_k] matmuls.  Causally-dead K/V tiles are skipped
 with ``pl.when`` (and the index maps never fetch them twice).
 
-Layout: q [B, Sq, Hq, D], k/v [B, Skv, Hkv, D] — grid
-(B, Hq, Sq/block_q, Skv/block_k), last axis "arbitrary" (sequential).
+Layout: the public contract is q [B, Sq, Hq, D], k/v [B, Skv, Hkv, D].
+The wrapper transposes to head-major [B, H, S, D] so that every block's
+last two dims are (rows, D): tile-aligned rows and the whole head dim, as
+the TPU compiler requires.  Grid (B, Hq, Sq/block_q, Skv/block_k), last
+axis "arbitrary" (sequential).
 """
 
 from __future__ import annotations
@@ -24,12 +27,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 LANES = 128
+# scores and P·V in f32 throughout, as in the reference: exact f32
+# matmuls on the MXU (the default precision would round them to bf16)
+_PREC = jax.lax.Precision.HIGHEST
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -62,10 +64,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(jnp.logical_not(tile_dead))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)           # [bq, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # [bk, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                   # [bq, d]
+        k = k_ref[...].astype(jnp.float32)                   # [bk, d]
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=_PREC,
                                 preferred_element_type=jnp.float32)
         s = s * scale
         if softcap:
@@ -84,7 +87,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         p = jnp.exp(s - m_new)                               # [bq, bk]
         l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc = acc_scr[...] * alpha + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            p, v, precision=_PREC, preferred_element_type=jnp.float32)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
         acc_scr[...] = acc
@@ -93,7 +96,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         l = l_scr[:, 0:1]
         out = acc_scr[...] / jnp.maximum(l, 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -114,12 +117,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     bq = min(block_q, sq)
     bk = min(block_k, skv)
     pq, pk = (-sq) % bq, (-skv) % bk
-    if pq:
-        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
-    if pk:
-        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    nq, nk = q.shape[1] // bq, k.shape[1] // bk
+    # head-major [B, H, S, D]; padded rows are masked (kv) or dropped (q)
+    q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    nq, nk = q.shape[2] // bq, k.shape[2] // bk
 
     grid = (b, hq, nq, nk)
     kern = functools.partial(
@@ -130,25 +132,24 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b, h, i, j, g=g: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b, h, i, j, g=g: (b, j, h // g, 0)),
+            pl.BlockSpec((None, None, bq, d),
+                         lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bk, d),
+                         lambda b, h, i, j, g=g: (b, h // g, j, 0)),
+            pl.BlockSpec((None, None, bk, d),
+                         lambda b, h, i, j, g=g: (b, h // g, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, d),
-                               lambda b, h, i, j: (b, i, h, 0)),
+        out_specs=pl.BlockSpec((None, None, bq, d),
+                               lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(q, k, v)
-    if pq:
-        out = out[:, :sq]
-    return out
+    return out.transpose(0, 2, 1, 3)[:, :sq]

@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret=None`` auto-selects: real lowering on TPU backends, interpret
-mode elsewhere (this container is CPU-only; kernels are TPU-target and
-validated in interpret mode per the task spec).
+``interpret=None`` auto-selects: real lowering on TPU, the Pallas
+interpreter on the CPU (where the tests validate the kernels).  Any other
+backend is an error: the kernels are written for the TPU only.
 """
 
 from __future__ import annotations
@@ -20,7 +20,12 @@ from repro.kernels import ssd_scan as ssd
 def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels target the TPU; backend {backend!r} has "
+            "neither their lowering nor the CPU interpreter")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -7,7 +7,11 @@ chunk).  This kernel runs them on the MXU with all chunk operands resident
 in VMEM; the cheap O(S) decay cumsums and the tiny inter-chunk recurrence
 stay in XLA (see repro.models.ssm.ssd_chunked for the reference pipeline).
 
-Grid: (B, H, n_chunks); blocks: one chunk per program instance.
+Grid: (B, H, n_chunks); blocks: one chunk per program instance.  The
+wrapper lays the per-head operands out head-major ([B, H, S, P], and the
+log-decay both as a column [B, H, S, 1] and as a row [B, H, 1, S]) so that
+every block's last two dims are tile-aligned or whole, as the TPU
+compiler requires.
 """
 
 from __future__ import annotations
@@ -20,36 +24,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+# f32 operands throughout: exact f32 matmuls on the MXU, as in the
+# XLA reference (the default precision would round them to bf16)
+_PREC = jax.lax.Precision.HIGHEST
 
 
-def _kernel(xdt_ref, acs_ref, b_ref, c_ref, y_ref, st_ref, *, chunk: int):
-    # xdt: [1, Q, 1, P] (x*dt); acs: [1, Q, 1] cumsum of a within chunk;
-    # b/c: [1, Q, N]
-    xdt = xdt_ref[0, :, 0, :].astype(jnp.float32)        # [Q, P]
-    acs = acs_ref[0, :, 0].astype(jnp.float32)           # [Q]
-    bm = b_ref[0].astype(jnp.float32)                    # [Q, N]
-    cm = c_ref[0].astype(jnp.float32)                    # [Q, N]
+def _kernel(xdt_ref, acol_ref, arow_ref, b_ref, c_ref, y_ref, st_ref, *,
+            chunk: int):
+    # xdt: [Q, P] (x*dt); acol/arow: [Q, 1] / [1, Q] cumsum of the
+    # log-decay within the chunk; b/c: [Q, N]
+    xdt = xdt_ref[...].astype(jnp.float32)
+    acol = acol_ref[...].astype(jnp.float32)
+    arow = arow_ref[...].astype(jnp.float32)
+    bm = b_ref[...].astype(jnp.float32)
+    cm = c_ref[...].astype(jnp.float32)
 
-    seg = acs[:, None] - acs[None, :]                    # [Q, Q]
+    seg = acol - arow                                    # [Q, Q]
     iq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     l_mat = jnp.where(iq >= jq, jnp.exp(seg), 0.0)
 
     scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
+                                 precision=_PREC,
                                  preferred_element_type=jnp.float32)
     scores = scores * l_mat                              # [Q, Q]
-    y = jax.lax.dot(scores, xdt,
+    y = jax.lax.dot(scores, xdt, precision=_PREC,
                     preferred_element_type=jnp.float32)  # [Q, P]
 
-    decay_st = jnp.exp(acs[-1] - acs)                    # [Q]
-    b_dec = bm * decay_st[:, None]                       # [Q, N]
+    decay_st = jnp.exp(acol[chunk - 1:chunk, :] - acol)  # [Q, 1]
+    b_dec = bm * decay_st                                # [Q, N]
     states = jax.lax.dot_general(b_dec, xdt, (((0,), (0,)), ((), ())),
+                                 precision=_PREC,
                                  preferred_element_type=jnp.float32)
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
-    st_ref[0, 0, 0] = states.astype(st_ref.dtype)        # [N, P]
+    y_ref[...] = y.astype(y_ref.dtype)
+    st_ref[...] = states.astype(st_ref.dtype)            # [N, P]
 
 
 def ssd_intra_chunk(xdt: jnp.ndarray, a_cs: jnp.ndarray, b_mat: jnp.ndarray,
@@ -67,26 +75,33 @@ def ssd_intra_chunk(xdt: jnp.ndarray, a_cs: jnp.ndarray, b_mat: jnp.ndarray,
     n = b_mat.shape[-1]
     assert s % chunk == 0, (s, chunk)
     nc = s // chunk
-    grid = (bsz, h, nc)
+    xdt_hm = xdt.transpose(0, 2, 1, 3)                   # [B, H, S, P]
+    a_hm = a_cs.transpose(0, 2, 1)                       # [B, H, S]
     y, st = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
-        grid=grid,
+        grid=(bsz, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda b, hh, c: (b, c, hh, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, hh, c: (b, c, hh)),
-            pl.BlockSpec((1, chunk, n), lambda b, hh, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b, hh, c: (b, c, 0)),
+            pl.BlockSpec((None, None, chunk, p),
+                         lambda b, hh, c: (b, hh, c, 0)),
+            pl.BlockSpec((None, None, chunk, 1),
+                         lambda b, hh, c: (b, hh, c, 0)),
+            pl.BlockSpec((None, None, 1, chunk),
+                         lambda b, hh, c: (b, hh, 0, c)),
+            pl.BlockSpec((None, chunk, n), lambda b, hh, c: (b, c, 0)),
+            pl.BlockSpec((None, chunk, n), lambda b, hh, c: (b, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda b, hh, c: (b, c, hh, 0)),
-            pl.BlockSpec((1, 1, 1, n, p), lambda b, hh, c: (b, c, hh, 0, 0)),
+            pl.BlockSpec((None, None, chunk, p),
+                         lambda b, hh, c: (b, hh, c, 0)),
+            pl.BlockSpec((None, None, None, n, p),
+                         lambda b, hh, c: (b, c, hh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, h, p), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, h, s, p), jnp.float32),
             jax.ShapeDtypeStruct((bsz, nc, h, n, p), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(xdt, a_cs, b_mat, c_mat)
-    return y, st
+    )(xdt_hm, a_hm[..., None], a_hm[:, :, None, :], b_mat, c_mat)
+    return y.transpose(0, 2, 1, 3), st

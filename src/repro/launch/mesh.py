@@ -9,20 +9,13 @@ set XLA_FLAGS before first jax init).
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.4.35 exposes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

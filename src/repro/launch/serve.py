@@ -19,6 +19,7 @@ from repro.configs import get_arch, reduced
 from repro.core import Aggregator, JobManifest, TrainMonitor, query
 from repro.core.report import generate_report
 from repro.core.transport import Shipper, StreamFileSink
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, mesh_num_chips
 from repro.models import Model, ModelOptions
 from repro.train.serve import ServeEngine, ServeRequest
@@ -36,6 +37,7 @@ def main(argv=None) -> int:
     ap.add_argument("--report", action="store_true")
     ap.add_argument("--use-pallas", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     workdir = Path(args.workdir)
     cfg = get_arch(args.arch)

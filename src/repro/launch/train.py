@@ -1,6 +1,8 @@
 """Production training launcher with integrated monitoring.
 
-Runs a real (CPU-sized here, mesh-agnostic by construction) training job:
+Runs a real training job (``--reduced`` sizes on the CPU, published widths
+on a TPU — see chip_smoke.py; on several chips the parameters, optimizer
+state and batch are sharded over a ("data", "model") mesh):
 data pipeline -> jit'd train step -> checkpointing -> hpcmd monitoring ->
 per-job report.  This is the end-to-end driver used by the examples and
 by the elastic supervisor (launch/elastic.py), which restarts this
@@ -23,8 +25,8 @@ import time
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch, reduced
@@ -39,6 +41,7 @@ from repro.optim import AdamW, OptimizerConfig
 from repro.optim.optimizer import OptState
 from repro.train import StepConfig, make_train_step
 from repro.train.sharding import ShardingCtx, param_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, mesh_num_chips
 
 
@@ -56,7 +59,17 @@ def build_config(args) -> ArchConfig:
     return cfg
 
 
-def main(argv=None) -> int:
+def build_model(cfg: ArchConfig, args, ctx=None) -> Model:
+    return Model(cfg, ctx=ctx, options=ModelOptions(
+        remat_policy=args.remat, attn_chunk=max(256, args.seq_len // 2)))
+
+
+def build_optimizer(args) -> AdamW:
+    return AdamW(OptimizerConfig(lr=args.lr, warmup_steps=10,
+                                 total_steps=max(args.steps, 11)))
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -75,7 +88,8 @@ def main(argv=None) -> int:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--remat", default="full",
                     choices=["none", "full", "dots", "dots_no_batch"])
-    ap.add_argument("--use-pallas", action="store_true")
+    ap.add_argument("--use-pallas", action="store_true",
+                    help="refused: the Pallas kernels are forward-only")
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--corpus", default=None,
                     help="binary uint32 token corpus (else synthetic)")
@@ -89,17 +103,22 @@ def main(argv=None) -> int:
     ap.add_argument("--fail-at-step", type=int, default=0,
                     help="crash deliberately (fault-tolerance demos)")
     args = ap.parse_args(argv)
+    if args.use_pallas:
+        ap.error("--use-pallas: the Pallas kernels have no backward pass, "
+                 "so they cannot train; they serve (launch/serve.py)")
+    return args
 
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
     workdir = Path(args.workdir)
     workdir.mkdir(parents=True, exist_ok=True)
     cfg = build_config(args)
     mesh = make_local_mesh(args.model_axis)
     ctx = ShardingCtx(mesh=mesh) if mesh_num_chips(mesh) > 1 else None
-    model = Model(cfg, ctx=ctx, options=ModelOptions(
-        use_pallas=args.use_pallas, remat_policy=args.remat,
-        attn_chunk=max(256, args.seq_len // 2)))
-    optimizer = AdamW(OptimizerConfig(lr=args.lr, warmup_steps=10,
-                                      total_steps=max(args.steps, 11)))
+    model = build_model(cfg, args, ctx)
+    optimizer = build_optimizer(args)
     job_id = args.job_id or f"train.{cfg.name}.{os.getpid()}"
     manifest = JobManifest(
         job_id=job_id, user=os.environ.get("USER", "user"),
@@ -112,6 +131,14 @@ def main(argv=None) -> int:
                            enabled=not args.no_monitor)
 
     # ---- state init / resume ------------------------------------------
+    # on a mesh, parameters and optimizer moments follow the sharding
+    # rules (ZeRO-style over "data") and the batch splits over "data"
+    key = jax.random.PRNGKey(0)
+    p_shard = opt_shard = None
+    if ctx is not None:
+        p_shard = param_shardings(jax.eval_shape(model.init, key), ctx)
+        opt_shard = OptState(NamedSharding(mesh, PartitionSpec()),
+                             p_shard, p_shard)
     ckpt = CheckpointManager(workdir / "ckpt", keep=3,
                              host_id=args.host_id)
     start_step = 0
@@ -120,17 +147,20 @@ def main(argv=None) -> int:
         restored = ckpt.restore_latest()
         if restored is not None:
             start_step, tree, meta = restored
-            params = jax.tree_util.tree_map(jnp.asarray, tree["params"])
             o = tree["opt"]
-            opt_state = OptState(count=jnp.asarray(o["count"]),
-                                 mu=jax.tree_util.tree_map(
-                                     jnp.asarray, o["mu"]),
-                                 nu=jax.tree_util.tree_map(
-                                     jnp.asarray, o["nu"]))
+            params = jax.device_put(tree["params"], p_shard)
+            opt_state = jax.device_put(
+                OptState(o["count"], o["mu"], o["nu"]), opt_shard)
             print(f"[train] resumed from step {start_step}", flush=True)
     if params is None:
-        params = model.init(jax.random.PRNGKey(0))
-        opt_state = optimizer.init(params)
+        params = jax.jit(model.init, out_shardings=p_shard)(key)
+        opt_state = jax.jit(optimizer.init, out_shardings=opt_shard)(params)
+
+    def put_batch(host_batch):
+        return {k: jax.device_put(v, None if ctx is None else NamedSharding(
+                    mesh, ctx.spec(("batch",) + (None,) * (v.ndim - 1),
+                                   v.shape)))
+                for k, v in host_batch.items()}
 
     # ---- data -----------------------------------------------------------
     if args.corpus:
@@ -148,14 +178,18 @@ def main(argv=None) -> int:
     step_fn = make_train_step(model, optimizer, StepConfig(
         num_microbatches=args.microbatches,
         compress_grads=args.compress_grads))
-    sample = {k: jnp.asarray(v) for k, v in source.get(start_step).items()}
-    jitted = jax.jit(step_fn, donate_argnums=(0, 1))
-    lowered = jitted.lower(params, opt_state, None, sample)
-    compiled = lowered.compile()
+    sample = put_batch(source.get(start_step))
+    jitted = jax.jit(step_fn, donate_argnums=(0, 1),
+                     out_shardings=(p_shard, opt_shard, None, None))
+    t_compile = time.perf_counter()
+    compiled = jitted.lower(params, opt_state, None, sample).compile()
+    t_compile = time.perf_counter() - t_compile
     figures = monitor.register_compiled(
         compiled, tokens_per_step=args.batch * args.seq_len)
-    print(f"[train] compiled: {figures['flops']:.3e} flops/step/dev, "
-          f"dominant={figures['dominant']}", flush=True)
+    print(f"[train] compiled in {t_compile:.2f}s: "
+          f"{figures['flops']:.3e} flops/step/dev, "
+          f"dominant={figures.get('dominant', 'no peak for this device')}",
+          flush=True)
 
     # ---- loop -----------------------------------------------------------
     t_last = time.time()
@@ -167,7 +201,7 @@ def main(argv=None) -> int:
             print(f"[train] injected failure at step {step}", flush=True)
             os._exit(17)
         t0 = time.perf_counter()
-        batch = {k: jnp.asarray(v) for k, v in pipe.next().items()}
+        batch = put_batch(pipe.next())
         wait = time.perf_counter() - t0
         params, opt_state, _, metrics = compiled(params, opt_state, None,
                                                  batch)
@@ -176,6 +210,7 @@ def main(argv=None) -> int:
                         tokens=args.batch * args.seq_len)
         if (step + 1) % args.checkpoint_every == 0 \
                 or step + 1 == args.steps:
+            t_save = time.perf_counter()
             ckpt.save(step + 1, {
                 "params": jax.tree_util.tree_map(np.asarray, params),
                 "opt": {"count": np.asarray(opt_state.count),
@@ -183,6 +218,8 @@ def main(argv=None) -> int:
                                                      opt_state.mu),
                         "nu": jax.tree_util.tree_map(np.asarray,
                                                      opt_state.nu)}})
+            print(f"[train] checkpoint at step {step + 1} saved in "
+                  f"{time.perf_counter() - t_save:.2f}s", flush=True)
         if (step + 1) % 10 == 0 or step == start_step:
             dt = time.time() - t_last
             t_last = time.time()

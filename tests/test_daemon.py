@@ -117,3 +117,43 @@ def test_manifest_roundtrip(tmp_path):
     got = JobManifest.load(tmp_path / "m.json")
     assert got == man
     assert JobManifest.load(tmp_path / "missing.json") is None
+
+
+def test_hardware_keyed_by_device_kind():
+    import pytest
+    from repro.core.derived import TPU_V5E, hardware_for, local_hardware
+    assert hardware_for("tpu", "TPU v5 lite") is TPU_V5E
+    assert hardware_for("cpu", "cpu") is None
+    assert local_hardware() is None          # the tests run on the CPU
+    with pytest.raises(KeyError):
+        hardware_for("tpu", "TPU v99")
+
+
+def test_perf_record_has_mfu_only_against_known_peaks():
+    from repro.core.derived import TPU_V5E
+    from repro.core.sources import StaticStepCost, StepClock, XlaCostSource
+    fields = {}
+    for hw in (None, TPU_V5E):
+        clock = StepClock()
+        src = XlaCostSource(clock, hw)
+        src.set_cost(StaticStepCost(flops=1e12, bytes=1e10))
+        clock.record(1, ts=10.0)
+        src.collect(10.0)                    # anchors the window
+        clock.record(3, ts=12.0)
+        fields[hw] = src.collect(12.0)
+    assert "mfu" not in fields[None] and fields[None]["gflops"] > 0
+    assert fields[TPU_V5E]["mfu"] > 0
+
+
+def test_monitor_on_cpu_writes_no_roofline(tmp_path):
+    import pytest
+    from repro.core.hooks import TrainMonitor
+
+    class NoText:
+        def as_text(self):
+            raise RuntimeError("executable cannot be serialized")
+
+    mon = TrainMonitor(tmp_path, JobManifest(job_id="j"), enabled=False)
+    assert mon.hw is None
+    with pytest.raises(RuntimeError):
+        mon.register_compiled(NoText())

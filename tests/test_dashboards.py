@@ -4,6 +4,7 @@ import numpy as np
 
 from repro.core.aggregator import MetricStore
 from repro.core.daemon import JobManifest
+from repro.core.derived import TPU_V5E
 from repro.core.dashboards import (JobPoint, job_metric_series,
                                    job_statistical_view, markdown_table,
                                    render_roofline_svg,
@@ -43,11 +44,11 @@ def test_roofline_points_and_svg():
     store, manifests = build_store()
     pts = roofline_points(store, manifests)
     assert len(pts) == 3
-    svg = render_roofline_svg(pts)
+    svg = render_roofline_svg(pts, TPU_V5E)
     assert svg.startswith("<svg") and svg.count("<circle") >= 3
     assert "GFLOP/s per chip" in svg
     # empty store still renders axes
-    assert render_roofline_svg([]).startswith("<svg")
+    assert render_roofline_svg([], TPU_V5E).startswith("<svg")
 
 
 def test_timeseries_svg():

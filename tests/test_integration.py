@@ -17,7 +17,8 @@ SRC = Path(__file__).resolve().parents[1] / "src"
 
 def test_monitored_training_end_to_end(tmp_path):
     from repro.configs import get_arch, reduced
-    from repro.core import Aggregator, JobManifest, TrainMonitor, query
+    from repro.core import (TPU_V5E, Aggregator, JobManifest,
+                            TrainMonitor, query)
     from repro.core.report import generate_report
     from repro.core.transport import Shipper, StreamFileSink
     from repro.models import Model, ModelOptions
@@ -34,7 +35,7 @@ def test_monitored_training_end_to_end(tmp_path):
     man = JobManifest(job_id="it.1", app=cfg.name, num_hosts=1,
                       num_chips=1)
     mon = TrainMonitor(tmp_path, man, host="h0", interval_s=0.0,
-                       align_to_clock=False)
+                       hw=TPU_V5E, align_to_clock=False)
     src = SyntheticSource(cfg, 32, 4)
     pipe = Pipeline(src, stats=mon.pipeline_stats)
     step = make_train_step(model, opt, StepConfig(ce_seq_chunk=16))
@@ -125,3 +126,18 @@ def test_dryrun_single_cell_subprocess():
     assert rec["ok"] and rec["chips"] == 256
     assert rec["fits_hbm"]
     assert rec["dominant"] in ("compute", "memory", "collective")
+
+
+def test_compile_cache_location(monkeypatch):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(SRC.parent / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -131,3 +131,13 @@ def test_ssd_op_property(s, h, p, n, seed):
     y2, h2 = ssd_chunked(x, dt, a_log, bm, cm, 16)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-4)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=2e-4)
+
+
+def test_kernels_refuse_backends_without_lowering(monkeypatch):
+    """Interpret mode is the CPU's; elsewhere only the TPU lowering runs."""
+    from repro.kernels import ops
+    assert ops._auto_interpret(None) is True          # the tests' CPU
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="TPU"):
+        ops._auto_interpret(None)
+    assert ops._auto_interpret(True) is True
