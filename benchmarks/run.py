@@ -24,7 +24,6 @@ trajectory is tracked across PRs.
                   pre/post, byte ratio, rollup vs raw scan
   restart       — aggregator cold-start: mmap segments vs line replay
   transport     — rsyslog-analog throughput
-  kernels.*     — Pallas kernels vs jnp oracles (interpret mode)
 """
 
 import json
@@ -47,7 +46,6 @@ def _parse_row(line: str):
 
 
 def main() -> None:
-    from benchmarks import kernels as kbench
     from benchmarks import monitoring as mbench
     from benchmarks.bench_faults import bench_faults
     from benchmarks.bench_replication import bench_replication
@@ -73,9 +71,6 @@ def main() -> None:
         mbench.bench_compaction,
         mbench.bench_restart,
         mbench.bench_transport,
-        kbench.bench_flash_attention,
-        kbench.bench_ssd,
-        kbench.bench_xla_attention_paths,
     ]
     if only:
         benches = [b for b in benches

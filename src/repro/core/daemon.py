@@ -26,6 +26,7 @@ from typing import Dict, List, Optional
 
 from repro.core.schema import MetricRecord, encode_line
 from repro.core.sources import MetricSource
+from repro.core.telemetry import Telemetry
 from repro.core.transport import Spool
 
 
@@ -76,6 +77,11 @@ class Hpcmd:
     Deterministic embedding: call :meth:`tick` directly (tests, in-loop
     usage).  Background embedding: :meth:`start` / :meth:`stop` run the
     same tick loop in a daemon thread.
+
+    Each sampling round is a ``repro.monitor.tick`` span with one
+    ``repro.monitor.sample`` child per source (attribute ``kind``), in
+    the daemon's ``telemetry``: written into a running profiler
+    session's trace, free otherwise.
     """
 
     def __init__(self, spool_dir: os.PathLike,
@@ -85,6 +91,7 @@ class Hpcmd:
         self.config = config or DaemonConfig()
         self.host = host or socket.gethostname()
         self.manifest = manifest
+        self.telemetry = Telemetry(node=self.host)
         self.spool = Spool(spool_dir,
                            max_segment_bytes=self.config.max_segment_bytes,
                            fsync=self.config.spool_fsync)
@@ -147,18 +154,20 @@ class Hpcmd:
             return 0
         job = self.manifest.job_id if self.manifest else "idle"
         written = 0
-        for src in self.sources:
-            if src.once and id(src) in self._once_done:
-                continue
-            fields = src.safe_collect(now)
-            if fields is None:
-                continue
-            if src.once:
-                self._once_done.add(id(src))
-            rec = MetricRecord(ts=now, host=self.host, job=job,
-                               kind=src.kind, fields=fields)
-            self.spool.write_line(encode_line(rec))
-            written += 1
+        with self.telemetry.span("repro.monitor.tick") as tick:
+            for src in self.sources:
+                if src.once and id(src) in self._once_done:
+                    continue
+                with tick.child("repro.monitor.sample", {"kind": src.kind}):
+                    fields = src.safe_collect(now)
+                    if fields is None:
+                        continue
+                    if src.once:
+                        self._once_done.add(id(src))
+                    rec = MetricRecord(ts=now, host=self.host, job=job,
+                                       kind=src.kind, fields=fields)
+                    self.spool.write_line(encode_line(rec))
+                    written += 1
         self.samples_written += written
         return written
 

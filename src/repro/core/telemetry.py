@@ -106,13 +106,14 @@ class Span:
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "node",
                  "start", "duration_s", "status", "attrs",
-                 "_t0", "_tracer", "_finished")
+                 "_t0", "_tracer", "_finished", "_annotation")
 
     recording = True
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_id: Optional[str],
-                 attrs: Optional[Dict[str, Any]] = None) -> None:
+                 attrs: Optional[Dict[str, Any]] = None,
+                 annotation: Any = None) -> None:
         self.trace_id = trace_id
         self.span_id = _new_id()
         self.parent_id = parent_id
@@ -125,6 +126,7 @@ class Span:
         self._t0 = time.monotonic()
         self._tracer = tracer
         self._finished = False
+        self._annotation = annotation
 
     # -- attribute + lifecycle --------------------------------------------
     def set(self, **attrs: Any) -> "Span":
@@ -144,6 +146,8 @@ class Span:
             return self
         self._finished = True
         self.duration_s = time.monotonic() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         if status is not None:
             self.status = status
         self._tracer._record(self)
@@ -207,6 +211,43 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _profiler_annotation(name: str, attrs: Optional[Dict[str, Any]]):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``, entered, while a
+    profiler session runs in this process; else None.  JAX is only looked
+    up where it is already loaded, so the fleet's processes never import
+    it for this."""
+    jax = sys.modules.get("jax")
+    annotation = getattr(getattr(jax, "profiler", None),
+                         "TraceAnnotation", None)
+    if annotation is None or not annotation.is_enabled():
+        return None
+    ann = annotation(name, **(attrs or {}))
+    ann.__enter__()
+    return ann
+
+
+class _ProfilerSpan(_NullSpan):
+    """A disabled tracer's span while a profiler session runs: it enters
+    and exits its ``TraceAnnotation`` and records nothing."""
+
+    __slots__ = ("_tracer", "_annotation")
+
+    def __init__(self, tracer: "Tracer", annotation: Any) -> None:
+        self._tracer, self._annotation = tracer, annotation
+
+    def child(self, name: str, attrs: Optional[Dict] = None):
+        return self._tracer.start_span(name, parent=self, attrs=attrs)
+
+    def finish(self, status: Optional[str] = None) -> "_ProfilerSpan":
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.finish()
+
+
 class Tracer:
     """Produces spans and collects finished traces.
 
@@ -215,7 +256,13 @@ class Tracer:
     buffer, and traces slower than ``slow_threshold_s`` are copied
     into the slow-query log with an exemplar.  Spans adopted from
     remote processes (:meth:`adopt`) splice into whichever table
-    currently holds the trace.  All public methods are thread-safe."""
+    currently holds the trace.  All public methods are thread-safe.
+
+    While a JAX profiler session runs, every span also enters a
+    ``jax.profiler.TraceAnnotation`` of its name, so the span lands in
+    that session's trace on the device trace's clock.  A disabled
+    tracer's span then does only that; outside a session it is
+    ``NULL_SPAN``."""
 
     def __init__(self, enabled: bool = True, node: str = "coordinator",
                  ring_max: int = TRACE_RING_MAX,
@@ -240,8 +287,10 @@ class Tracer:
         """Start a span.  ``parent`` links locally; ``parent_ctx``
         (a ``{"trace_id", "span_id"}`` dict off the wire) links across
         processes.  With neither, a new root trace begins."""
+        annotation = _profiler_annotation(name, attrs)
         if not self.enabled:
-            return NULL_SPAN
+            return (NULL_SPAN if annotation is None
+                    else _ProfilerSpan(self, annotation))
         if parent is not None and parent.recording:
             trace_id, parent_id = parent.trace_id, parent.span_id
         elif parent_ctx and parent_ctx.get("trace_id"):
@@ -251,7 +300,7 @@ class Tracer:
             trace_id, parent_id = _new_id(), None
         with self._lock:
             self.spans_started += 1
-        return Span(self, name, trace_id, parent_id, attrs)
+        return Span(self, name, trace_id, parent_id, attrs, annotation)
 
     # -- thread-local "current span" --------------------------------------
     def current(self):
@@ -625,7 +674,9 @@ class Telemetry:
     ``Telemetry(tracing=True)`` to record spans.  The instance is
     inherited downward — ``QueryService`` adopts its store's
     telemetry, the remote aggregator shares its instance with every
-    ``RemoteShard``/``ReplicaSet`` member."""
+    ``RemoteShard``/``ReplicaSet`` member.  The training path's monitor
+    and input pipeline own a ``Telemetry()`` each: their spans reach a
+    running profiler session's trace and nothing else (:class:`Tracer`)."""
 
     def __init__(self, tracing: bool = False, node: str = "coordinator",
                  slow_threshold_s: float = SLOW_QUERY_THRESHOLD_S,

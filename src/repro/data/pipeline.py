@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.sources import PipelineStats
+from repro.core.telemetry import Telemetry
 
 
 class SyntheticSource:
@@ -116,12 +117,17 @@ class MemmapSource:
 
 
 class Pipeline:
-    """Background-prefetching wrapper with monitoring hooks."""
+    """Background-prefetching wrapper with monitoring hooks.
+
+    :meth:`next`'s wait for the queue is a ``repro.pipeline.wait`` span in
+    the pipeline's ``telemetry``, written into a running profiler
+    session's trace."""
 
     def __init__(self, source, stats: Optional[PipelineStats] = None,
                  prefetch: int = 2, start_step: int = 0):
         self.source = source
         self.stats = stats or PipelineStats()
+        self.telemetry = Telemetry()
         self._q: queue.Queue = queue.Queue(maxsize=max(prefetch, 1))
         self._stop = threading.Event()
         self._step = start_step
@@ -142,7 +148,8 @@ class Pipeline:
 
     def next(self) -> Dict[str, np.ndarray]:
         t0 = time.perf_counter()
-        step, batch = self._q.get()
+        with self.telemetry.span("repro.pipeline.wait"):
+            step, batch = self._q.get()
         wait = time.perf_counter() - t0
         tokens = int(batch.get("tokens", batch.get("embeds")).shape[0]
                      * self.source.seq_len)

@@ -182,6 +182,7 @@ def _split_xbc(cfg, x_bc):
     return x_bc[..., :di], x_bc[..., di:di + n], x_bc[..., di + n:]
 
 
+@jax.named_scope("model.ssm")
 def apply_ssm_mixer(params, cfg, u: jnp.ndarray,
                     state: Optional[Dict[str, jnp.ndarray]] = None,
                     return_state: bool = False,

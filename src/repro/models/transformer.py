@@ -15,6 +15,14 @@ Key structural decisions (see DESIGN.md §5):
   cross block), so cross params exist only where they are used.
 * **remat** — each block body can be wrapped in ``jax.checkpoint`` with a
   selectable policy (a §Perf lever).
+* **named scopes** — the training step's work carries stable
+  ``jax.named_scope`` names, so a device trace can be read by layer:
+  ``model.embed``, ``model.layers`` (the layer scan), ``model.block`` (a
+  block's norms, residual adds and hybrid fuse), ``model.ssm``,
+  ``model.attention``, ``model.mlp``, ``model.loss`` (final norm, logits,
+  cross entropy; ``train/step.py``) and ``optim.update``
+  (``optim/optimizer.py``).  Backward ops carry their forward's scope
+  as ``transpose(jvp(<scope>))``, custom-VJP backward rules included.
 """
 
 from __future__ import annotations
@@ -233,6 +241,7 @@ class Model:
             cap=cfg.attn_logit_softcap, scale=self._scale(),
             chunk=self.opt.attn_chunk, q_chunk=self.opt.attn_q_chunk)
 
+    @jax.named_scope("model.attention")
     def _self_attention(self, p, x, positions, window, theta):
         """Pre-norm self attention over the fresh sequence (train/prefill).
         Returns (block output, (k, v)) — k/v feed the prefill cache."""
@@ -243,6 +252,7 @@ class Model:
         out = self._attend_seq(q, k, v, positions, window)
         return self._attn_out(p, out), (k, v)
 
+    @jax.named_scope("model.mlp")
     def _mlp(self, p, x):
         cfg = self.cfg
         h = rms_norm(x, p["norm_scale"], cfg.norm_eps)
@@ -297,6 +307,7 @@ class Model:
         return x
 
     # -------------------------------------------------------------- embed
+    @jax.named_scope("model.embed")
     def embed_inputs(self, params, batch) -> jnp.ndarray:
         cfg = self.cfg
         if cfg.frontend == "audio_frames":
@@ -334,6 +345,7 @@ class Model:
         return self._forward_trunk(params, batch)
 
     # ------------------------------------------------------- train forward
+    @jax.named_scope("model.block")
     def _block_train(self, bp, x, window, theta, positions, aux):
         cfg = self.cfg
         if cfg.family == "ssm":
@@ -358,7 +370,8 @@ class Model:
     def forward(self, params, batch) -> Tuple[jnp.ndarray, Dict]:
         """Full-sequence forward (training).  Returns (logits, aux)."""
         x, aux = self._forward_trunk(params, batch)
-        return self._logits(params, x), aux
+        with jax.named_scope("model.loss"):
+            return self._logits(params, x), aux
 
     def _forward_trunk(self, params, batch) -> Tuple[jnp.ndarray, Dict]:
         cfg = self.cfg
@@ -406,17 +419,19 @@ class Model:
             if remat:
                 group_body = jax.checkpoint(group_body, policy=policy,
                                             prevent_cse=self.opt.remat_prevent_cse)
-            (x, aux), _ = jax.lax.scan(
-                group_body, (x, aux0),
-                (grouped, windows, thetas,
-                 jnp.arange(n_groups, dtype=jnp.int32)))
+            with jax.named_scope("model.layers"):
+                (x, aux), _ = jax.lax.scan(
+                    group_body, (x, aux0),
+                    (grouped, windows, thetas,
+                     jnp.arange(n_groups, dtype=jnp.int32)))
         else:
             scanned = (jax.checkpoint(body, policy=policy,
                                    prevent_cse=self.opt.remat_prevent_cse)
                        if remat else body)
-            (x, aux), _ = jax.lax.scan(scanned, (x, aux0),
-                                       (params["blocks"], self.windows,
-                                        self.thetas))
+            with jax.named_scope("model.layers"):
+                (x, aux), _ = jax.lax.scan(scanned, (x, aux0),
+                                           (params["blocks"], self.windows,
+                                            self.thetas))
         if cfg.num_meta_tokens:
             x = x[:, cfg.num_meta_tokens:]
         return x, aux

@@ -61,6 +61,7 @@ class AdamW:
         return OptState(count=jnp.zeros((), jnp.int32),
                         mu=zeros(params), nu=zeros(params))
 
+    @jax.named_scope("optim.update")
     def update(self, grads, state: OptState, params
                ) -> Tuple[Any, OptState, Dict[str, jnp.ndarray]]:
         cfg = self.cfg

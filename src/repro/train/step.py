@@ -26,6 +26,7 @@ class StepConfig:
                                    # beyond [B, chunk, V]; 0 disables
 
 
+@jax.named_scope("model.loss")
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
                   mask: jnp.ndarray, ctx=None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -50,6 +51,7 @@ def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
     return loss, acc
 
 
+@jax.named_scope("model.loss")
 def chunked_cross_entropy(model: Model, params, hidden, labels, mask,
                           seq_chunk: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """CE over sequence chunks with a hand-written VJP.
