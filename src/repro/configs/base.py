@@ -108,12 +108,11 @@ class ArchConfig:
         return self.attn_pattern.startswith("local_global")
 
     def layer_kinds(self) -> List[str]:
-        """Per-layer attention kind: 'global' | 'local' | 'ssm' | 'hybrid'."""
+        """Per-layer attention kind: 'global' | 'local' | 'ssm'.  A hybrid's
+        attention heads follow ``attn_pattern`` like any other's."""
         n = self.num_layers
         if self.family == "ssm":
             return ["ssm"] * n
-        if self.family == "hybrid":
-            return ["hybrid"] * n
         if self.attn_pattern == "global":
             return ["global"] * n
         if self.attn_pattern == "local_global_1_1":

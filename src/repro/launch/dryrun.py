@@ -50,7 +50,7 @@ OUT_ROOT = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 # paper-faithful configuration; named variants apply one optimization at a
 # time (EXPERIMENTS.md §Perf documents hypothesis/result for each).
 _BASE = dict(remat_policy="full", moe_group_size=2048, attn_chunk=2048,
-             attn_q_chunk=2048, num_microbatches=4, ssm_chunk=0,
+             num_microbatches=4, ssm_chunk=0,
              seq_rule=("model",))
 
 VARIANTS = {
@@ -94,7 +94,6 @@ def build_cell(arch_id: str, shape_id: str, multi_pod: bool,
         use_pallas=False,
         remat_policy=knobs["remat_policy"],
         attn_chunk=knobs["attn_chunk"],
-        attn_q_chunk=knobs.get("attn_q_chunk", 4096),
         moe_group_size=knobs["moe_group_size"]))
     in_specs = specs_mod.input_specs(arch, shape)
     in_sh = specs_mod.input_shardings(ctx, in_specs)
@@ -155,10 +154,8 @@ def build_cell(arch_id: str, shape_id: str, multi_pod: bool,
     # VMEM on real TPUs (XLA fallback materializes them).
     attn_chunk = knobs["attn_chunk"]
     ssm_q = arch.ssm_chunk
-
-    q_chunk = knobs.get("attn_q_chunk", 4096)
     seq_like = {shape.seq_len, shape.seq_len + arch.num_meta_tokens,
-                attn_chunk, q_chunk}
+                attn_chunk}
 
     def tag(result_type: str) -> str:
         shapes = hlo_cost_mod._shape_dims(result_type)

@@ -23,8 +23,10 @@ import os
 import sys
 import time
 from pathlib import Path
+from typing import Dict
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
@@ -33,10 +35,12 @@ from repro.configs import get_arch, reduced
 from repro.configs.base import ArchConfig
 from repro.core import (Aggregator, JobManifest, TrainMonitor, query)
 from repro.core.report import generate_report
+from repro.core.telemetry import Registry
 from repro.core.transport import Shipper, StreamFileSink
 from repro.data import Pipeline, SyntheticSource
 from repro.data.pipeline import MemmapSource
 from repro.models import Model, ModelOptions
+from repro.models.attention import tile_plan
 from repro.optim import AdamW, OptimizerConfig
 from repro.optim.optimizer import OptState
 from repro.train import StepConfig, make_train_step
@@ -60,8 +64,27 @@ def build_config(args) -> ArchConfig:
 
 
 def build_model(cfg: ArchConfig, args, ctx=None) -> Model:
-    return Model(cfg, ctx=ctx, options=ModelOptions(
-        remat_policy=args.remat, attn_chunk=max(256, args.seq_len // 2)))
+    return Model(cfg, ctx=ctx, options=ModelOptions(remat_policy=args.remat))
+
+
+def record_attention_tiles(model: Model, seq_len: int,
+                           registry: Registry) -> Dict[str, float]:
+    """Share of the attention tiles the train step computes, by layer kind
+    (``local``, ``global``), from ``tile_plan`` at the step's length (meta
+    tokens included); set as the gauge ``repro.attention.live_tile_share``.
+    """
+    cfg = model.cfg
+    if not cfg.has_attention:
+        return {}
+    pos = jnp.arange(seq_len + cfg.num_meta_tokens, dtype=jnp.int32)
+    shares = {}
+    for kind in sorted(set(cfg.layer_kinds())):
+        plan = tile_plan(pos, pos, chunk=model.opt.attn_chunk, causal=True,
+                         window=cfg.window_size if kind == "local" else None)
+        shares[kind] = int(plan.sum()) / plan.size
+        registry.gauge("repro.attention.live_tile_share",
+                       kind=kind).set(shares[kind])
+    return shares
 
 
 def build_optimizer(args) -> AdamW:
@@ -190,6 +213,10 @@ def main(argv=None) -> int:
           f"{figures['flops']:.3e} flops/step/dev, "
           f"dominant={figures.get('dominant', 'no peak for this device')}",
           flush=True)
+    tiles = record_attention_tiles(model, args.seq_len,
+                                   monitor.daemon.telemetry.registry)
+    if tiles:
+        print(f"[train] attention tiles computed: {tiles}", flush=True)
 
     # ---- loop -----------------------------------------------------------
     t_last = time.time()

@@ -56,8 +56,7 @@ class ModelOptions:
     use_pallas: bool = False
     remat_policy: str = "full"        # applied to train forward only
     remat_prevent_cse: bool = True    # keep saved residuals in model dtype
-    attn_chunk: int = 2048            # online-softmax KV blocking threshold
-    attn_q_chunk: int = 4096          # query blocking for long prefills
+    attn_chunk: int = 256             # attention tile edge; longer keys tile
     moe_group_size: int = 2048
 
 
@@ -239,7 +238,7 @@ class Model:
         return attn_mod.attend(
             q, k, v, positions, positions, causal=True, window=window,
             cap=cfg.attn_logit_softcap, scale=self._scale(),
-            chunk=self.opt.attn_chunk, q_chunk=self.opt.attn_q_chunk)
+            chunk=self.opt.attn_chunk)
 
     @jax.named_scope("model.attention")
     def _self_attention(self, p, x, positions, window, theta):
