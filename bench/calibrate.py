@@ -9,7 +9,8 @@ In one process, for each seed: the program's compared steps (the same
 first ``--control`` seeds, the control (the reference in fp8) against the
 reference: the upper readings. For the first ``--faults`` seeds, the
 program with a planted fault (half of the batch left out; one leaf's
-update applied twice) against the reference. One JSON line per reading;
+update applied twice; on more than one chip, the gradient's exchange
+between chips left out) against the reference. One JSON line per reading;
 the benchmark's runs never run this.
 """
 
@@ -120,7 +121,9 @@ def main(argv=None) -> int:
                   "control_s": t_ctl})
             del ctl
         if i < args.faults:
-            for fault in ("half_batch", "double_leaf"):
+            faults = ("half_batch", "double_leaf") + (
+                ("no_exchange",) if cell.workload["chips"] > 1 else ())
+            for fault in faults:
                 fp = program(seed, fault)
                 nums, where = compare.gaps(fp, ref)
                 emit({"kind": fault, "seed": seed, **nums, "leaves": where,

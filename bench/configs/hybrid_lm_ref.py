@@ -182,8 +182,8 @@ class Reference:
 
     def mixer(self, q, u):
         c = self.c
-        sz = layout.ssm_sizes(c)
-        di, nh, n = sz["d_inner"], sz["heads"], c["ssm_state"]
+        di = c["ssm_expand"] * c["d_model"]
+        nh, n = di // c["ssm_headdim"], c["ssm_state"]
         b, s, _ = u.shape
         proj = self.mm("bsd,de->bse", rms(u, q["norm_scale"], c["norm_eps"]),
                        q["in_proj"])

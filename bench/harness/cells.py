@@ -3,8 +3,9 @@
 ``BENCHMARK.json`` at the checkout's root names each cell's configuration,
 traffic mix and metrics. Each part is a file of its own under ``bench/``:
 
-* ``configs/<config>.json``: the sizes as run, with the plain reference
-  module it names beside it;
+* ``configs/<config>.json``: the sizes as run, with the module of its
+  parameter shapes and model FLOPs (``shapes``) and the plain reference
+  module (``reference``) that it names beside it;
 * ``traffic/<mix>.json``: the parameters the general generator reads;
 * ``metrics/<metric>.py``: a reader with ``read(r) -> float | None``;
 * ``limits/<workload>.json``: the limit of each number compared.
@@ -15,6 +16,7 @@ changes.
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass, field
@@ -26,12 +28,26 @@ ROOT = BENCH.parent
 
 
 def _load_module(path: Path, name: str):
+    if not path.is_file():
+        raise FileNotFoundError(f"no module {path}")
     spec = importlib.util.spec_from_file_location(name, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _shapes_at(path: Path):
+    return _load_module(path, f"shapes_{path.stem}")
+
+
+def shapes(config: dict, bench: Path = BENCH):
+    """The module of the configuration's parameter shapes and model FLOPs,
+    ``configs/<shapes>``: ``leaves(c)``, ``forward_flops_per_sequence(c,
+    seq_len)``, ``matmul_param_count(c)``. Loaded once per file."""
+    return _shapes_at(bench / "configs" / config["shapes"])
 
 
 @dataclass
@@ -47,6 +63,9 @@ class Cell:
     @property
     def name(self) -> str:
         return self.workload["name"]
+
+    def shapes(self):
+        return shapes(self.config, self.bench)
 
     def reference(self):
         return _load_module(self.bench / "configs" / self.config["reference"],
@@ -79,6 +98,7 @@ def find(workload: str, root: Path = ROOT) -> Cell:
     entry = configs[w["config"]]
     config = json.loads((root / entry["file"]).read_text())
     bench = root / "bench"
+    shapes(config, bench)
     traffic = json.loads(
         (bench / "traffic" / f"{w['traffic']}.json").read_text())
     e2e = [m for m in bm["end_to_end"]

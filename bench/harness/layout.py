@@ -1,94 +1,35 @@
-"""Parameter layout of the language models the training cells run, and
-their weights from the seed.
+"""Weights from the seed, in the parameter layout of the language models
+the training cells run.
 
 The layout is the program's pytree (``models/transformer.py``), written
-down here from the configuration file alone so that the reference and the
-program are fed the same weights without either making them. Every leaf is
-drawn from its own key, folded from the seed, in one jitted call, and
-stored in the type the program trains it in: the model dtype, except the
-SSM's ``dt_bias``, ``a_log``, ``d_skip`` and the hybrid mix's betas, which
-stay float32.
+down from the configuration file alone by the shapes module the
+configuration names, so that the reference and the program are fed the
+same weights without either making them. Every leaf is drawn from its own
+key, folded from the seed, in one jitted call, and stored in the type the
+shapes module gives. This module also draws the positions the comparison
+samples from each leaf.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Tuple
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-# (path, shape, dtype, init, std); init is one of
-# normal | trunc | zeros | ones | a_log
-Leaf = Tuple[Tuple[str, ...], Tuple[int, ...], str, str, float]
+from harness import cells
 
 # elements of each leaf that the comparison reads whole (sample_leaves)
 SAMPLE = 1 << 16
 
 
-def ssm_sizes(c: dict) -> Dict[str, int]:
-    d_inner = c["ssm_expand"] * c["d_model"]
-    return {"d_inner": d_inner, "heads": d_inner // c["ssm_headdim"],
-            "conv_ch": d_inner + 2 * c["ssm_state"]}
-
-
-def leaves(c: dict) -> List[Leaf]:
-    """Every leaf of the parameter tree of configuration ``c``."""
-    d, L, V, dt = c["d_model"], c["num_layers"], c["vocab_size"], c["dtype"]
-    f32 = "float32"
-    out: List[Leaf] = [
-        (("embed", "table"), (V, d), dt, "normal", 0.02),
-        (("final_norm_scale",), (d,), dt, "zeros", 0.0),
-    ]
-    if not c["tie_embeddings"]:
-        out.append((("lm_head",), (d, V), dt, "trunc", d ** -0.5))
-    if c["num_heads"]:
-        hq, hkv, hd = c["num_heads"], c["num_kv_heads"], c["head_dim"]
-        a = ("blocks", "attn")
-        out += [
-            (a + ("norm_scale",), (L, d), dt, "zeros", 0.0),
-            (a + ("wq",), (L, d, hq, hd), dt, "trunc", d ** -0.5),
-            (a + ("wk",), (L, d, hkv, hd), dt, "trunc", d ** -0.5),
-            (a + ("wv",), (L, d, hkv, hd), dt, "trunc", d ** -0.5),
-            (a + ("wo",), (L, hq, hd, d), dt, "trunc", (hq * hd) ** -0.5),
-        ]
-    if c["ssm_state"]:
-        s = ssm_sizes(c)
-        di, nh, n = s["d_inner"], s["heads"], c["ssm_state"]
-        b = ("blocks", "ssm")
-        out += [
-            (b + ("norm_scale",), (L, d), dt, "zeros", 0.0),
-            (b + ("in_proj",), (L, d, 2 * di + 2 * n + nh), dt, "trunc",
-             d ** -0.5),
-            (b + ("conv_w",), (L, c["conv_width"], s["conv_ch"]), dt,
-             "trunc", c["conv_width"] ** -0.5),
-            (b + ("dt_bias",), (L, nh), f32, "zeros", 0.0),
-            (b + ("a_log",), (L, nh), f32, "a_log", 0.0),
-            (b + ("d_skip",), (L, nh), f32, "ones", 0.0),
-            (b + ("gate_norm_scale",), (L, di), dt, "zeros", 0.0),
-            (b + ("out_proj",), (L, di, d), dt, "trunc", di ** -0.5),
-        ]
-    if c["num_heads"] and c["ssm_state"]:
-        f = ("blocks", "fuse")
-        out += [
-            (f + ("attn_norm",), (L, d), dt, "zeros", 0.0),
-            (f + ("ssm_norm",), (L, d), dt, "zeros", 0.0),
-            (f + ("beta_attn",), (L,), f32, "ones", 0.0),
-            (f + ("beta_ssm",), (L,), f32, "ones", 0.0),
-        ]
-    if c["d_ff"]:
-        m, ff = ("blocks", "mlp"), c["d_ff"]
-        out += [
-            (m + ("norm_scale",), (L, d), dt, "zeros", 0.0),
-            (m + ("w_gate",), (L, d, ff), dt, "trunc", d ** -0.5),
-            (m + ("w_up",), (L, d, ff), dt, "trunc", d ** -0.5),
-            (m + ("w_down",), (L, ff, d), dt, "trunc", ff ** -0.5),
-        ]
-    if c["num_meta_tokens"]:
-        out.append((("meta_tokens",), (c["num_meta_tokens"], d), dt,
-                    "normal", 0.02))
-    return out
+def leaves(c: dict):
+    """Every leaf of configuration ``c``'s parameter tree, as its shapes
+    module (``cells.shapes``) writes them: (path, shape, dtype, init,
+    std)."""
+    return cells.shapes(c).leaves(c)
 
 
 def seed_key(seed: int):
@@ -127,9 +68,11 @@ def leaf_names(tree) -> List[str]:
             for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
 
 
-def make_params(c: dict, seed: int) -> dict:
-    """The whole parameter tree from the seed, on the default device, in one
-    jitted call. Equal seeds give equal trees."""
+def make_params(c: dict, seed: int, shardings=None) -> dict:
+    """The whole parameter tree from the seed in one jitted call: on the
+    default device, or where ``shardings`` (a tree like the parameters')
+    puts each leaf, so that no leaf is ever whole on one chip. Equal seeds
+    give equal trees, however they are placed."""
     spec = leaves(c)
 
     def build(key):
@@ -139,7 +82,7 @@ def make_params(c: dict, seed: int) -> dict:
             set_path(tree, path, value.astype(dtype))
         return tree
 
-    return jax.jit(build)(seed_key(seed))
+    return jax.jit(build, out_shardings=shardings)(seed_key(seed))
 
 
 def sample_positions(tree, seed: int, k: int = SAMPLE) -> List[np.ndarray]:
@@ -169,15 +112,3 @@ def sample_leaves(tree, seed: int, k: int = SAMPLE) -> Dict[str, np.ndarray]:
     pos = sample_positions(tree, seed, k)
     return dict(zip(leaf_names(tree),
                     (np.asarray(v) for v in _gather(tree, pos))))
-
-
-def matmul_param_count(c: dict) -> int:
-    """Parameters that enter a matrix multiplication (all but norms,
-    biases and per-head scalars); the unembedding counts once when tied."""
-    n = 0
-    for path, shape, _, init, _ in leaves(c):
-        name = path[-1]
-        if name in ("table", "lm_head", "wq", "wk", "wv", "wo", "in_proj",
-                    "out_proj", "w_gate", "w_up", "w_down", "conv_w"):
-            n += math.prod(shape)
-    return n

@@ -13,6 +13,13 @@ Output:
 * ``spans``: total seconds and count of each host span name;
 * ``device_ops``: the ten operations with most device time of their
   own (less the operations nested in them);
+* ``collective_s``: the device time of the collective operations (by the
+  HLO opcode their names carry: all-gather, reduce-scatter, all-reduce,
+  collective-permute, all-to-all, and the TPU's async-collective, each
+  with its ``-start`` and ``-done`` halves), averaged over the devices:
+  the collectives that run on their own. One that the compiler runs
+  inside a fusion beside a matmul is hidden behind it, and counts as that
+  fusion's compute;
 * ``idle_gaps``: the ten longest gaps between device operations, each
   named by the host span that overlaps it most (``host`` if none does).
 """
@@ -21,6 +28,7 @@ from __future__ import annotations
 
 import collections
 import glob
+import re
 from typing import Dict, Iterable, List, Sequence, Tuple
 
 Event = Tuple[str, float, float]          # name, start ns, duration ns
@@ -48,6 +56,16 @@ def _clip(events: Sequence[Event], lo: float, hi: float):
 def op_name(name: str) -> str:
     """``%fusion.12 = bf16[...] fusion(...)`` -> ``fusion.12``."""
     return name.split(" = ", 1)[0].lstrip("%")
+
+
+_COLLECTIVE = re.compile(r"(all-gather|reduce-scatter|all-reduce|"
+                         r"collective-permute|all-to-all|async-collective)"
+                         r"(-start|-done)?(\.\d+)*")
+
+
+def is_collective(name: str) -> bool:
+    """Whether an operation's name carries a collective's opcode."""
+    return _COLLECTIVE.fullmatch(op_name(name)) is not None
 
 
 def self_times(events) -> List[Tuple[str, float]]:
@@ -78,11 +96,14 @@ def reduce(device_events: Dict[str, Sequence[Event]],
     lo, hi = windows[0]
     window_s = (hi - lo) * 1e-9
     busy, op_time = [], collections.Counter()
+    collective_s = 0.0
     gaps: List[Tuple[float, float]] = []
     for events in device_events.values():
         clipped = list(_clip(events, lo, hi))
         for name, t in self_times(clipped):
             op_time[name] += t
+            if is_collective(name):
+                collective_s += t
         u = union((a, b) for _, a, b in clipped)
         busy.append(sum(b - a for a, b in u) * 1e-9)
         edges = [lo] + [x for ab in u for x in ab] + [hi]
@@ -113,6 +134,7 @@ def reduce(device_events: Dict[str, Sequence[Event]],
         "spans": {n: {"seconds": v[0], "count": v[1]}
                   for n, v in spans.items()},
         "device_ops": [[n, t] for n, t in op_time.most_common(top)],
+        "collective_s": collective_s / len(device_events),
         "idle_gaps": [[label(a, b), (b - a) * 1e-9] for a, b in gaps[:top]],
     }
 

@@ -40,9 +40,10 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 
 def main(argv=None, *, device_check=None, config_update=None,
-         traffic_update=None, fault=None) -> int:
-    """``device_check``, ``config_update``, ``traffic_update`` and ``fault``
-    let the benchmark's own tests drive a run on the CPU at a small size
+         traffic_update=None, limits_update=None, fault=None) -> int:
+    """``device_check``, ``config_update``, ``traffic_update``,
+    ``limits_update`` and ``fault`` let the benchmark's own tests drive a
+    run on the CPU at a small size, against limits read at that size,
     with a planted fault; a measured run leaves them unset."""
     args = parse_args(argv)
     from harness import cells, device
@@ -55,6 +56,7 @@ def main(argv=None, *, device_check=None, config_update=None,
         return 2
     cell.config.update(config_update or {})
     cell.traffic.update(traffic_update or {})
+    cell.limits.update(limits_update or {})
     try:
         dev = (device_check or device.check)(cell.workload["chips"])
     except device.NoChip as e:

@@ -13,12 +13,14 @@ from harness import cells, compare
 from harness.train_cell import Job, reference_batches
 
 
-@pytest.mark.parametrize("workload", ["mamba2-780m.train", "hymba-1.5b.train"])
+@pytest.mark.parametrize("workload", ["mamba2-780m.train", "hymba-1.5b.train",
+                                      "hymba-1.5b-32l.train-4chip"])
 @pytest.mark.parametrize("seed", [3, 2**31 + 5, 4_000_000_007])
 def test_control_reads_above_the_program(workload, seed, tmp_path):
     cell = cells.find(workload)
     cell.config.update(tiny.TINY[cell.config["name"]])
     cell.traffic.update(tiny.TRAFFIC)
+    cell.limits.update(tiny.LIMITS.get(workload, {}))
     ref = cell.reference()
     batches = reference_batches(cell, seed)
     c, t = cell.config, cell.config["train"]

@@ -4,7 +4,7 @@ import json
 
 import pytest
 
-from harness import cells, flops, layout
+from harness import cells, flops
 
 
 def config(name):
@@ -38,17 +38,18 @@ def test_hybrid_hand_count_is_window_aware():
     # layers 0, 1, 2 are all anchors of a three-layer stack: global
     fwd = (3 * ((ssm + mlp) * t + diag) + 3 * attn(keys_global)
            + 2 * 8 * 10 * seq)
-    assert flops.forward_flops_per_sequence(c, seq) == fwd
+    assert cells.shapes(c).forward_flops_per_sequence(c, seq) == fwd
     c5 = dict(c, num_layers=5)                  # anchors 0, 2, 4; 1, 3 local
     fwd5 = (5 * ((ssm + mlp) * t + diag) + 3 * attn(keys_global)
             + 2 * attn(keys_local) + 2 * 8 * 10 * seq)
-    assert flops.forward_flops_per_sequence(c5, seq) == fwd5
+    assert cells.shapes(c5).forward_flops_per_sequence(c5, seq) == fwd5
 
 
-@pytest.mark.parametrize("name", ["mamba2-780m", "hymba-1.5b"])
+@pytest.mark.parametrize("name", ["mamba2-780m", "hymba-1.5b",
+                                  "hymba-1.5b-32l"])
 def test_at_least_six_n_tokens(name):
     c = config(name)
-    n = layout.matmul_param_count(c)
+    n = cells.shapes(c).matmul_param_count(c)
     tokens = 2048 * 4
     f = flops.train_step_flops(c, 2048, 4)
     assert f >= 6 * n * tokens

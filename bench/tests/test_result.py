@@ -33,10 +33,13 @@ def test_sound_tiny_run_is_correct(plain):
     assert plain[1]["correct"] is True, plain[1]["checks"]
 
 
-def test_traced_line_carries_per_layer_metrics():
-    rc, r, _ = tiny.run("mamba2-780m.train", trace=1)
+@pytest.mark.parametrize("workload", ["mamba2-780m.train",
+                                      "hymba-1.5b-32l.train-4chip"])
+def test_traced_line_carries_per_layer_metrics(workload):
+    rc, r, _ = tiny.run(workload, trace=1)
     assert rc == 0
-    names = {m["name"] for m in cells.find("mamba2-780m.train").per_layer}
+    assert r["device"]["count"] == cells.find(workload).workload["chips"]
+    names = {m["name"] for m in cells.find(workload).per_layer}
     assert set(r["metrics"]) <= names
     assert "step_mfu" in r["metrics"]          # no device trace on the CPU
     assert 0 < r["metrics"]["step_mfu"]["value"] < 100
