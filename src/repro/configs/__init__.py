@@ -17,6 +17,7 @@ from repro.configs import (  # noqa: F401  isort: skip
     qwen3_8b,
     hymba_1_5b,
     llama_3_2_vision_11b,
+    nemotron_3_nano_30b_a3b,
 )
 
 ARCH_IDS = list_archs()

@@ -24,7 +24,7 @@ class ArchConfig:
     """
 
     name: str
-    family: str  # dense | moe | ssm | hybrid | audio | vlm
+    family: str  # dense | moe | ssm | hybrid | interleaved | audio | vlm
     num_layers: int
     d_model: int
     num_heads: int  # 0 for attention-free (SSM) architectures
@@ -43,19 +43,34 @@ class ArchConfig:
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     rope_theta_global: float = 0.0  # 0 -> same as rope_theta
+    rope: bool = True             # False: attention without positions
+
+    # --- interleaved: one mixer per layer, in the order of this pattern ----
+    # "M" Mamba-2 mixer, "E" expert layer, "*" attention (one char a layer)
+    layer_pattern: str = ""
 
     # --- MoE -----------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0          # the router's width
     experts_per_token: int = 0
-    moe_capacity_factor: float = 1.25
+    # experts whose weights live here; 0 -> all.  Fewer than num_experts is
+    # one chip's cut of an expert-parallel layout, whose held experts take
+    # exactly their balanced share of each sequence's slots (models/moe.py)
+    moe_experts_held: int = 0
+    moe_router: str = "softmax"   # softmax | sigmoid (bias picks, not weighs)
+    moe_routed_scale: float = 1.0
+    expert_act: str = "swiglu"    # swiglu: down(silu(gate x) up x) | relu2
     shared_expert: bool = False  # Llama-4 style always-on shared expert
+    shared_expert_ff: int = 0     # 0 -> d_ff
 
     # --- SSM (Mamba-2 SSD) ---------------------------------------------------
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_headdim: int = 64
     ssm_chunk: int = 256
+    ssm_num_heads: int = 0        # 0 -> ssm_expand * d_model // ssm_headdim
+    ssm_groups: int = 1           # B/C groups, each shared by heads/groups
     conv_width: int = 4
+    conv_bias: bool = False
 
     # --- hybrid (Hymba) ------------------------------------------------------
     parallel_ssm: bool = False  # attention + SSM heads fused in one block
@@ -85,11 +100,26 @@ class ArchConfig:
     @property
     def d_inner(self) -> int:
         """SSM inner width."""
+        if self.ssm_num_heads:
+            return self.ssm_num_heads * self.ssm_headdim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_headdim if self.ssm_state else 0
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the SSM's convolution: x, then B and C per group."""
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.num_experts
+
+    @property
+    def shared_ff(self) -> int:
+        return self.shared_expert_ff or self.d_ff
 
     @property
     def has_attention(self) -> bool:
@@ -113,6 +143,12 @@ class ArchConfig:
         n = self.num_layers
         if self.family == "ssm":
             return ["ssm"] * n
+        if self.layer_pattern:
+            if len(self.layer_pattern) != n:
+                raise ValueError(f"layer_pattern {self.layer_pattern!r} has "
+                                 f"{len(self.layer_pattern)} layers, not {n}")
+            kinds = {"M": "ssm", "E": "moe", "*": "global"}
+            return [kinds[ch] for ch in self.layer_pattern]
         if self.attn_pattern == "global":
             return ["global"] * n
         if self.attn_pattern == "local_global_1_1":
@@ -132,6 +168,38 @@ class ArchConfig:
         return [i for i in range(self.num_layers)
                 if (i + 1) % self.cross_attn_every == 0]
 
+    def _attn_params(self) -> int:
+        d, hd = self.d_model, self.resolved_head_dim
+        n_q, n_kv = self.num_heads, self.num_kv_heads
+        attn = d * n_q * hd + 2 * d * n_kv * hd + n_q * hd * d
+        if self.qk_norm:
+            attn += 2 * hd
+        return attn + d  # + input norm
+
+    def _ssm_params(self) -> int:
+        d, di, nh = self.d_model, self.d_inner, self.ssm_heads
+        conv = self.ssm_conv_dim
+        # in_proj -> (z, x, B, C, dt); conv (+ bias) on (x, B, C); out_proj
+        ssm = d * (di + conv + nh) + self.conv_width * conv
+        if self.conv_bias:
+            ssm += conv
+        ssm += nh * 3  # A_log, D, dt_bias
+        ssm += di      # gated norm
+        ssm += di * d  # out_proj
+        return ssm + d  # norm
+
+    def _expert_params(self, ff: int) -> int:
+        return (3 if self.expert_act == "swiglu" else 2) * self.d_model * ff
+
+    def _moe_params(self) -> int:
+        moe = self.d_model * self.num_experts  # router
+        if self.moe_router == "sigmoid":
+            moe += self.num_experts            # selection bias
+        moe += self.experts_held * self._expert_params(self.d_ff)
+        if self.shared_expert:
+            moe += self._expert_params(self.shared_ff)
+        return moe + self.d_model  # pre-FFN norm
+
     def param_count(self) -> int:
         """Exact parameter count of the model as built by models/transformer.py."""
         d, hd = self.d_model, self.resolved_head_dim
@@ -140,27 +208,17 @@ class ArchConfig:
         if not self.tie_embeddings:
             total += self.vocab_size * d  # lm head
         total += d  # final norm
+        if self.layer_pattern:
+            per_kind = {"ssm": self._ssm_params(), "moe": self._moe_params(),
+                        "global": self._attn_params()}
+            return total + sum(per_kind[k] for k in self.layer_kinds())
         per_layer = 0
         if self.has_attention:
-            attn = d * n_q * hd + 2 * d * n_kv * hd + n_q * hd * d
-            if self.qk_norm:
-                attn += 2 * hd
-            per_layer += attn + d  # + input norm
+            per_layer += self._attn_params()
         if self.family in ("ssm", "hybrid"):
-            di, st, nh = self.d_inner, self.ssm_state, self.ssm_heads
-            # in_proj -> (z, x, B, C, dt) ; conv on (x,B,C); out_proj
-            ssm = d * (2 * di + 2 * st + nh)
-            ssm += self.conv_width * (di + 2 * st)
-            ssm += nh * 2  # A_log, D
-            ssm += di * d  # out_proj
-            ssm += d  # norm
-            per_layer += ssm
+            per_layer += self._ssm_params()
         if self.is_moe:
-            per_layer += d * self.num_experts  # router
-            per_layer += self.num_experts * 3 * d * self.d_ff
-            if self.shared_expert:
-                per_layer += 3 * d * self.d_ff
-            per_layer += d  # pre-FFN norm
+            per_layer += self._moe_params()
         elif self.d_ff:
             per_layer += 3 * d * self.d_ff + d  # gated MLP + norm
         total += per_layer * self.num_layers
@@ -175,10 +233,11 @@ class ArchConfig:
         """Active parameters per token (MoE: only routed experts count)."""
         if not self.is_moe:
             return self.param_count()
-        dense_like = self.param_count()
-        skipped = (self.num_experts - self.experts_per_token)
-        per_layer_expert = 3 * self.d_model * self.d_ff
-        return dense_like - skipped * per_layer_expert * self.num_layers
+        moe_layers = (self.layer_kinds().count("moe") if self.layer_pattern
+                      else self.num_layers)
+        skipped = self.experts_held - self.experts_per_token
+        return (self.param_count()
+                - skipped * self._expert_params(self.d_ff) * moe_layers)
 
 
 # --------------------------------------------------------------------------
@@ -212,9 +271,12 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         n_kv = max(1, min(cfg.num_kv_heads, 2))
         while n_q % n_kv:
             n_kv -= 1
+    # an interleaved model keeps each kind of layer once, in pattern order
+    pattern = "".join(dict.fromkeys(cfg.layer_pattern))
     updates = dict(
         name=cfg.name + "-smoke",
-        num_layers=min(cfg.num_layers, 4),
+        num_layers=len(pattern) if pattern else min(cfg.num_layers, 4),
+        layer_pattern=pattern,
         d_model=128,
         num_heads=n_q,
         num_kv_heads=n_kv,
@@ -223,8 +285,12 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         vocab_size=512,
         num_experts=min(cfg.num_experts, 4),
         experts_per_token=min(cfg.experts_per_token, 2),
+        moe_experts_held=min(cfg.moe_experts_held, 4),
+        shared_expert_ff=96 if cfg.shared_expert_ff else 0,
         ssm_state=min(cfg.ssm_state, 16),
         ssm_headdim=32 if cfg.ssm_state else 64,
+        ssm_num_heads=min(cfg.ssm_num_heads, 8),
+        ssm_groups=min(cfg.ssm_groups, 2),
         ssm_chunk=32,
         num_meta_tokens=min(cfg.num_meta_tokens, 8),
         cross_attn_every=2 if cfg.cross_attn_every else 0,

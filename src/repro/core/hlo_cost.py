@@ -7,7 +7,10 @@ graph (entry -> while bodies / fusions / calls), extracts loop trip counts
 from the loop-condition constants, and accumulates:
 
 * **flops** — 2 x prod(result_dims) x prod(contraction_dims) per ``dot``
-  (including dots inside fusion subcomputations), x loop multiplier;
+  (including dots inside fusion subcomputations), and per TPU grouped
+  matmul (``jax.lax.ragged_dot``, a ``ragged-dot`` custom call) 2 x its
+  row operand's elements x the result's last dim, at the static row
+  bound; x loop multiplier;
 * **traffic bytes** — operand + result bytes of every top-level op in a
   computation (post-fusion top-level ops are the kernel boundaries, i.e.
   the HBM traffic model), x loop multiplier;
@@ -263,6 +266,24 @@ def _dot_flops(inst: Instruction, comp: Computation) -> float:
     return 2.0 * result_elems * contract
 
 
+_LAYOUTS_RE = re.compile(r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=")
+
+
+def _ragged_dot_flops(inst: Instruction) -> float:
+    """A grouped matmul as the TPU lowers it: a custom call named
+    ``ragged-dot-*`` whose last two operands are the matrices. Forward,
+    lhs gradient and rhs gradient alike do 2 x (row operand's elements) x
+    (result's last dim) at the static row bound."""
+    if not inst.name.startswith("ragged-dot") or "metadata" in inst.name:
+        return 0.0
+    m = _LAYOUTS_RE.search(inst.line)
+    shapes = _shape_dims(m.group(1)) if m else []
+    result = _shape_dims(inst.result_type)
+    if len(shapes) < 2 or not result or not result[0][1]:
+        return 0.0
+    return 2.0 * math.prod(shapes[-2][1]) * result[0][1][-1]
+
+
 def _trip_count(cond: Computation) -> int:
     """Loop trip count: the largest integer constant in the condition
     computation (all our scans have static trip counts)."""
@@ -346,6 +367,8 @@ def analyze_hlo(hlo_text: str, tag_fn=None) -> HloCost:
             base = _normalize_opcode(op)
             if op in ("dot", "convolution"):
                 cost.flops += _dot_flops(inst, comp) * mult
+            elif op == "custom-call":
+                cost.flops += _ragged_dot_flops(inst) * mult
             if base in COLLECTIVE_KINDS and not op.endswith("-done"):
                 ob = comp.operand_bytes(inst)
                 rb = _bytes_of_type(inst.result_type)

@@ -49,7 +49,7 @@ OUT_ROOT = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 # Per-cell knobs for the §Perf hillclimb variants.  "baseline" is the
 # paper-faithful configuration; named variants apply one optimization at a
 # time (EXPERIMENTS.md §Perf documents hypothesis/result for each).
-_BASE = dict(remat_policy="full", moe_group_size=2048, attn_chunk=2048,
+_BASE = dict(remat_policy="full", attn_chunk=2048,
              num_microbatches=4, ssm_chunk=0,
              seq_rule=("model",))
 
@@ -67,8 +67,6 @@ VARIANTS = {
     "microbatch1": dict(_BASE, num_microbatches=1),
     "microbatch2": dict(_BASE, num_microbatches=2),
     "microbatch8": dict(_BASE, num_microbatches=8),
-    "moe_groups_8k": dict(_BASE, moe_group_size=8192),
-    "moe_groups_512": dict(_BASE, moe_group_size=512),
     "attn_chunk_4k": dict(_BASE, attn_chunk=4096),
     "attn_chunk_1k": dict(_BASE, attn_chunk=1024),
     "ssm_chunk_128": dict(_BASE, ssm_chunk=128),
@@ -93,8 +91,7 @@ def build_cell(arch_id: str, shape_id: str, multi_pod: bool,
     model = Model(arch, ctx=ctx, options=ModelOptions(
         use_pallas=False,
         remat_policy=knobs["remat_policy"],
-        attn_chunk=knobs["attn_chunk"],
-        moe_group_size=knobs["moe_group_size"]))
+        attn_chunk=knobs["attn_chunk"]))
     in_specs = specs_mod.input_specs(arch, shape)
     in_sh = specs_mod.input_shardings(ctx, in_specs)
     params_shape, _ = specs_mod.abstract_state(model)

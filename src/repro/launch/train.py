@@ -78,7 +78,7 @@ def record_attention_tiles(model: Model, seq_len: int,
         return {}
     pos = jnp.arange(seq_len + cfg.num_meta_tokens, dtype=jnp.int32)
     shares = {}
-    for kind in sorted(set(cfg.layer_kinds())):
+    for kind in sorted(set(cfg.layer_kinds()) & {"local", "global"}):
         plan = tile_plan(pos, pos, chunk=model.opt.attn_chunk, causal=True,
                          window=cfg.window_size if kind == "local" else None)
         shares[kind] = int(plan.sum()) / plan.size

@@ -42,10 +42,13 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
     """Chunked SSD scan.
 
     x: [B,S,H,P] inputs; dt: [B,S,H] (post-softplus); a_log: [H];
-    b_mat/c_mat: [B,S,N] (single group, broadcast over heads);
+    b_mat/c_mat: [B,S,N] (one group, broadcast over heads) or [B,S,G,N]
+    (G groups, each shared by H/G consecutive heads);
     h0: optional initial state [B,H,P,N].
     Returns (y [B,S,H,P], h_final [B,H,P,N]).
     """
+    if b_mat.ndim == 4:
+        return _ssd_grouped(x, dt, a_log, b_mat, c_mat, chunk, h0)
     bsz, s_orig, h, p = x.shape
     n = b_mat.shape[-1]
     pad = (-s_orig) % chunk
@@ -107,18 +110,41 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
     return y.astype(x.dtype), h_final
 
 
+def _ssd_grouped(x, dt, a_log, b_mat, c_mat, chunk, h0):
+    """``ssd_chunked`` once per B/C group, over that group's heads."""
+    bsz, s, h, p = x.shape
+    g = b_mat.shape[2]
+    hg = h // g
+    if h0 is None:
+        h0 = jnp.zeros((bsz, h, p, b_mat.shape[-1]), jnp.float32)
+    y, h_final = jax.vmap(
+        lambda x, dt, a, bm, cm, h0: ssd_chunked(x, dt, a, bm, cm, chunk, h0),
+        in_axes=(2, 2, 0, 2, 2, 1), out_axes=(2, 1))(
+        x.reshape(bsz, s, g, hg, p), dt.reshape(bsz, s, g, hg),
+        a_log.reshape(g, hg), b_mat, c_mat,
+        h0.reshape((bsz, g, hg) + h0.shape[2:]))
+    return (y.reshape(bsz, s, h, p),
+            h_final.reshape((bsz, h) + h_final.shape[3:]))
+
+
 def ssd_decode_step(h: jnp.ndarray, x: jnp.ndarray, dt: jnp.ndarray,
                     a_log: jnp.ndarray, b_mat: jnp.ndarray,
                     c_mat: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One recurrent step.  h: [B,H,P,N]; x: [B,H,P]; dt: [B,H];
-    b_mat/c_mat: [B,N].  Returns (y [B,H,P], h')."""
+    b_mat/c_mat: [B,N], or [B,G,N] with G groups of H/G heads.
+    Returns (y [B,H,P], h')."""
+    eq_in, eq_out = "bhp,bn->bhpn", "bhpn,bn->bhp"
+    if b_mat.ndim == 3:
+        hg = x.shape[1] // b_mat.shape[1]
+        b_mat, c_mat = (jnp.repeat(t, hg, axis=1) for t in (b_mat, c_mat))
+        eq_in, eq_out = "bhp,bhn->bhpn", "bhpn,bhn->bhp"
     h = h.astype(jnp.float32)
     dec = jnp.exp(-jnp.exp(a_log.astype(jnp.float32))[None, :]
                   * dt.astype(jnp.float32))                    # [B,H]
     xdt = x.astype(jnp.float32) * dt.astype(jnp.float32)[..., None]
-    upd = jnp.einsum("bhp,bn->bhpn", xdt, b_mat.astype(jnp.float32))
+    upd = jnp.einsum(eq_in, xdt, b_mat.astype(jnp.float32))
     h_new = h * dec[..., None, None] + upd
-    y = jnp.einsum("bhpn,bn->bhp", h_new, c_mat.astype(jnp.float32))
+    y = jnp.einsum(eq_out, h_new, c_mat.astype(jnp.float32))
     return y.astype(x.dtype), h_new
 
 
@@ -152,11 +178,13 @@ def conv1d_step(x: jnp.ndarray, w: jnp.ndarray, hist: jnp.ndarray
 def init_ssm_params(key, cfg, dtype) -> Dict[str, jnp.ndarray]:
     """Parameters for one Mamba-2 mixer (pre-norm included)."""
     d, di = cfg.d_model, cfg.d_inner
-    n, nh = cfg.ssm_state, cfg.ssm_heads
-    conv_ch = di + 2 * n
-    proj_out = 2 * di + 2 * n + nh   # z, xBC, dt
+    nh = cfg.ssm_heads
+    conv_ch = cfg.ssm_conv_dim
+    proj_out = di + conv_ch + nh     # z, xBC, dt
     k1, k2, k3, k4 = jax.random.split(key, 4)
+    extra = {"conv_b": jnp.zeros((conv_ch,), dtype)} if cfg.conv_bias else {}
     return {
+        **extra,
         "norm_scale": jnp.zeros((d,), dtype),
         "in_proj": dense_init(k1, (d, proj_out), dtype),
         "conv_w": trunc_normal(k2, (cfg.conv_width, conv_ch),
@@ -170,16 +198,34 @@ def init_ssm_params(key, cfg, dtype) -> Dict[str, jnp.ndarray]:
 
 
 def _split_proj(cfg, proj):
-    di, n, nh = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
+    di, conv = cfg.d_inner, cfg.ssm_conv_dim
     z = proj[..., :di]
-    x_bc = proj[..., di:di + di + 2 * n]
-    dt = proj[..., di + di + 2 * n:]
+    x_bc = proj[..., di:di + conv]
+    dt = proj[..., di + conv:]
     return z, x_bc, dt
 
 
 def _split_xbc(cfg, x_bc):
-    di, n = cfg.d_inner, cfg.ssm_state
-    return x_bc[..., :di], x_bc[..., di:di + n], x_bc[..., di + n:]
+    """x, B, C; with several groups B and C are [..., G, N]."""
+    di, gn = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
+    x, b_mat, c_mat = (x_bc[..., :di], x_bc[..., di:di + gn],
+                       x_bc[..., di + gn:])
+    if cfg.ssm_groups > 1:
+        shape = b_mat.shape[:-1] + (cfg.ssm_groups, cfg.ssm_state)
+        b_mat, c_mat = b_mat.reshape(shape), c_mat.reshape(shape)
+    return x, b_mat, c_mat
+
+
+def _gated_norm(cfg, params, y, z):
+    """RMSNorm of ``y * silu(z)``, over each group's channels."""
+    y = y * jax.nn.silu(z)
+    g = cfg.ssm_groups
+    if g == 1:
+        return rms_norm(y, params["gate_norm_scale"], cfg.norm_eps)
+    shape = y.shape[:-1] + (g, y.shape[-1] // g)
+    return rms_norm(y.reshape(shape),
+                    params["gate_norm_scale"].reshape(shape[-2:]),
+                    cfg.norm_eps).reshape(y.shape)
 
 
 @jax.named_scope("model.ssm")
@@ -199,6 +245,8 @@ def apply_ssm_mixer(params, cfg, u: jnp.ndarray,
     z, x_bc_pre, dt_raw = _split_proj(cfg, proj)
     hist0 = state["conv"] if state is not None else None
     x_bc = conv1d_causal(x_bc_pre, params["conv_w"], hist0)
+    if cfg.conv_bias:
+        x_bc = x_bc + params["conv_b"]
     x_bc = jax.nn.silu(x_bc)
     x, b_mat, c_mat = _split_xbc(cfg, x_bc)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
@@ -215,7 +263,7 @@ def apply_ssm_mixer(params, cfg, u: jnp.ndarray,
     y = y + params["d_skip"].astype(y.dtype)[None, None, :, None] \
         * xh
     y = y.reshape(bsz, s, cfg.d_inner)
-    y = rms_norm(y * jax.nn.silu(z), params["gate_norm_scale"], cfg.norm_eps)
+    y = _gated_norm(cfg, params, y, z)
     out = jnp.einsum("bse,ed->bsd", y, params["out_proj"])
     if not return_state:
         return out
@@ -237,6 +285,8 @@ def apply_ssm_decode(params, cfg, u: jnp.ndarray,
     proj = jnp.einsum("bd,de->be", x_in, params["in_proj"])
     z, x_bc, dt_raw = _split_proj(cfg, proj)
     x_bc, conv_hist = conv1d_step(x_bc, params["conv_w"], state["conv"])
+    if cfg.conv_bias:
+        x_bc = x_bc + params["conv_b"]
     x_bc = jax.nn.silu(x_bc)
     x, b_mat, c_mat = _split_xbc(cfg, x_bc)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
@@ -246,13 +296,13 @@ def apply_ssm_decode(params, cfg, u: jnp.ndarray,
                                b_mat, c_mat)
     y = y + params["d_skip"].astype(y.dtype)[None, :, None] * xh
     y = y.reshape(bsz, cfg.d_inner)
-    y = rms_norm(y * jax.nn.silu(z), params["gate_norm_scale"], cfg.norm_eps)
+    y = _gated_norm(cfg, params, y, z)
     out = jnp.einsum("be,ed->bd", y, params["out_proj"])[:, None, :]
     return out, {"ssm": h_new, "conv": conv_hist}
 
 
 def init_ssm_state(cfg, batch: int, dtype) -> Dict[str, jnp.ndarray]:
-    conv_ch = cfg.d_inner + 2 * cfg.ssm_state
+    conv_ch = cfg.ssm_conv_dim
     return {
         "ssm": jnp.zeros((batch, cfg.ssm_heads, cfg.ssm_headdim,
                           cfg.ssm_state), jnp.float32),

@@ -1,5 +1,7 @@
-"""The family-generic model: one scanned layer stack covering all ten
-assigned architectures (dense / MoE / SSM / hybrid / audio / VLM).
+"""The family-generic model: one scanned layer stack covering the assigned
+architectures (dense / MoE / SSM / hybrid / audio / VLM), and for an
+``interleaved`` model one stack per kind of layer, walked in the order of
+its ``layer_pattern``.
 
 Key structural decisions (see DESIGN.md §5):
 
@@ -13,13 +15,18 @@ Key structural decisions (see DESIGN.md §5):
 * **VLM grouping** — cross-attention blocks every k layers are handled by
   an outer scan over groups (inner scan over k self layers + one gated
   cross block), so cross params exist only where they are used.
+* **interleaved layers** — a model whose layers differ in kind (Mamba-2
+  mixer ``M``, expert layer ``E``, attention ``*``) keeps one parameter
+  stack per kind and walks its pattern layer by layer, each block under
+  the remat policy; its serving cache holds KV for the attention layers
+  and conv/SSM state for the Mamba layers, each stacked by its own kind.
 * **remat** — each block body can be wrapped in ``jax.checkpoint`` with a
   selectable policy (a §Perf lever).
 * **named scopes** — the training step's work carries stable
   ``jax.named_scope`` names, so a device trace can be read by layer:
   ``model.embed``, ``model.layers`` (the layer scan), ``model.block`` (a
   block's norms, residual adds and hybrid fuse), ``model.ssm``,
-  ``model.attention``, ``model.mlp``, ``model.loss`` (final norm, logits,
+  ``model.attention``, ``model.mlp``, ``model.moe``, ``model.loss`` (final norm, logits,
   cross entropy; ``train/step.py``) and ``optim.update``
   (``optim/optimizer.py``).  Backward ops carry their forward's scope
   as ``transpose(jvp(<scope>))``, custom-VJP backward rules included.
@@ -57,7 +64,6 @@ class ModelOptions:
     remat_policy: str = "full"        # applied to train forward only
     remat_prevent_cse: bool = True    # keep saved residuals in model dtype
     attn_chunk: int = 256             # attention tile edge; longer keys tile
-    moe_group_size: int = 2048
 
 
 class Model:
@@ -146,12 +152,18 @@ class Model:
                                            dt)
         blocks: Params = {}
         L = cfg.num_layers
+        n_attn = n_ssm = n_moe = L
+        if cfg.layer_pattern:
+            kinds = cfg.layer_kinds()
+            n_attn, n_ssm, n_moe = (kinds.count(k)
+                                    for k in ("global", "ssm", "moe"))
         if cfg.has_attention:
-            blocks["attn"] = self._init_attn(jax.random.fold_in(kB, 0), L)
-        if cfg.family in ("ssm", "hybrid"):
+            blocks["attn"] = self._init_attn(jax.random.fold_in(kB, 0),
+                                             n_attn)
+        if cfg.family in ("ssm", "hybrid", "interleaved"):
             blocks["ssm"] = self._init_stacked(
                 lambda k: ssm_mod.init_ssm_params(k, cfg, dt),
-                jax.random.fold_in(kB, 1), L)
+                jax.random.fold_in(kB, 1), n_ssm)
         if cfg.family == "hybrid":
             blocks["fuse"] = {
                 "attn_norm": jnp.zeros((L, cfg.d_model), dt),
@@ -162,7 +174,7 @@ class Model:
         if cfg.is_moe:
             blocks["moe"] = self._init_stacked(
                 lambda k: moe_mod.init_moe_params(k, cfg, dt),
-                jax.random.fold_in(kB, 2), L)
+                jax.random.fold_in(kB, 2), n_moe)
         elif cfg.d_ff:
             blocks["mlp"] = self._init_mlp(jax.random.fold_in(kB, 3), L)
         params["blocks"] = blocks
@@ -199,8 +211,9 @@ class Model:
         if cfg.qk_norm:
             q = rms_norm(q, p["q_norm"], cfg.norm_eps)
             k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-        q = rope(q, positions, theta)
-        k = rope(k, positions, theta)
+        if cfg.rope:
+            q = rope(q, positions, theta)
+            k = rope(k, positions, theta)
         q = self._constrain(q, "batch", None, "heads", None)
         k = self._constrain(k, "batch", None, "kv_heads", None)
         v = self._constrain(v, "batch", None, "kv_heads", None)
@@ -269,12 +282,10 @@ class Model:
                 + rms_norm(ssm_out, fuse["ssm_norm"], cfg.norm_eps)
                 * fuse["beta_ssm"].astype(ssm_out.dtype)) * 0.5
 
-    def _moe(self, bp, x, group_size=None):
-        y, aux = moe_mod.apply_moe(
-            bp["moe"], self.cfg,
-            rms_norm(x, bp["moe"]["norm_scale"], self.cfg.norm_eps),
-            self.ctx, group_size or self.opt.moe_group_size)
-        return y, aux
+    def _moe(self, p, x):
+        return moe_mod.apply_moe(
+            p, self.cfg, rms_norm(x, p["norm_scale"], self.cfg.norm_eps),
+            ctx=self.ctx)
 
     # ------------------------------------------------------------ VLM bits
     def _image_kv(self, params, batch):
@@ -359,9 +370,9 @@ class Model:
                                            window, theta)
         x = x + attn_out
         if cfg.is_moe:
-            y, a = self._moe(bp, x)
+            y, a = self._moe(bp["moe"], x)
             x = x + y
-            aux = {k: aux[k] + a[k] for k in aux}
+            aux = moe_mod.combine_aux(aux, a)
         elif cfg.d_ff:
             x = x + self._mlp(bp["mlp"], x)
         return x, aux
@@ -377,11 +388,12 @@ class Model:
         x = self.embed_inputs(params, batch)
         seq = x.shape[1]
         positions = jnp.arange(seq, dtype=jnp.int32)
-        aux0 = ({"moe_lb_loss": jnp.float32(0.0),
-                 "moe_z_loss": jnp.float32(0.0),
-                 "moe_drop_frac": jnp.float32(0.0)} if cfg.is_moe else {})
+        aux0 = moe_mod.aux_zeros(cfg) if cfg.is_moe else {}
         policy = REMAT_POLICIES.get(self.opt.remat_policy)
         remat = self.opt.remat_policy != "none"
+        if cfg.layer_pattern:
+            return self._pattern_trunk(params, x, positions, aux0,
+                                       policy if remat else False)
 
         def body(carry, xs):
             x, aux = carry
@@ -435,6 +447,105 @@ class Model:
             x = x[:, cfg.num_meta_tokens:]
         return x, aux
 
+    # --------------------------------------------------- interleaved layers
+    def _pattern_layers(self, params):
+        """(layer index, kind, its parameters) of each layer of an
+        interleaved model, in pattern order: layer ``i`` of a kind takes
+        the next slice of that kind's stack."""
+        stacks = {"ssm": "ssm", "moe": "moe", "global": "attn"}
+        taken: Dict[str, int] = {}
+        for i, kind in enumerate(self.cfg.layer_kinds()):
+            j = taken[kind] = taken.get(kind, -1) + 1
+            yield i, kind, jax.tree_util.tree_map(
+                lambda a: a[j], params["blocks"][stacks[kind]])
+
+    def _pattern_trunk(self, params, x, positions, aux, policy):
+        """Training forward of an interleaved model: each layer is
+        ``x + mixer(norm(x))`` (each mixer applies its own pre-norm),
+        checkpointed under ``policy`` unless it is False."""
+        cfg = self.cfg
+
+        def block(kind, i):
+            @jax.named_scope("model.block")
+            def run(p, x):
+                if kind == "ssm":
+                    return x + ssm_mod.apply_ssm_mixer(
+                        p, cfg, x, use_pallas=self.opt.use_pallas), {}
+                if kind == "moe":
+                    y, a = self._moe(p, x)
+                    return x + y, a
+                y, _ = self._self_attention(p, x, positions, self.windows[i],
+                                            self.thetas[i])
+                return x + y, {}
+            if policy is False:
+                return run
+            return jax.checkpoint(run, policy=policy,
+                                  prevent_cse=self.opt.remat_prevent_cse)
+
+        with jax.named_scope("model.layers"):
+            for i, kind, p in self._pattern_layers(params):
+                x, a = block(kind, i)(p, x)
+                if a:
+                    aux = moe_mod.combine_aux(aux, a)
+                x = self._constrain(x, "batch", "seq", "embed")
+        if cfg.num_meta_tokens:
+            x = x[:, cfg.num_meta_tokens:]
+        return x, aux
+
+    def _pattern_prefill(self, params, x, positions, extra_slots):
+        """Prefill of an interleaved model: KV of each attention layer and
+        conv/SSM state of each Mamba layer, each stacked by its kind."""
+        cfg = self.cfg
+        parts: Dict[str, list] = {"k": [], "v": [], "ssm": [], "conv": []}
+        for i, kind, p in self._pattern_layers(params):
+            if kind == "ssm":
+                y, st = ssm_mod.apply_ssm_mixer(
+                    p, cfg, x, use_pallas=self.opt.use_pallas,
+                    return_state=True)
+                parts["ssm"].append(st["ssm"])
+                parts["conv"].append(st["conv"])
+            elif kind == "moe":
+                y, _ = self._moe(p, x)
+            else:
+                y, (k, v) = self._self_attention(
+                    p, x, positions, self.windows[i], self.thetas[i])
+                if extra_slots:
+                    pad = ((0, 0), (0, extra_slots), (0, 0), (0, 0))
+                    k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+                parts["k"].append(k)
+                parts["v"].append(v)
+            x = self._constrain(x + y, "batch", "seq", "embed")
+        cache: Params = {"pos": jnp.asarray(x.shape[1], jnp.int32)}
+        cache.update({name: jnp.stack(ps) for name, ps in parts.items()
+                      if ps})
+        return self._logits(params, x[:, -1:]), cache
+
+    def _pattern_decode(self, params, x, cache, attn_decode):
+        """One-token decode of an interleaved model through its cache."""
+        cfg = self.cfg
+        parts: Dict[str, list] = {"k": [], "v": [], "ssm": [], "conv": []}
+        for i, kind, p in self._pattern_layers(params):
+            if kind == "ssm":
+                j = len(parts["ssm"])
+                y, st = ssm_mod.apply_ssm_decode(
+                    p, cfg, x, {"ssm": cache["ssm"][j],
+                                "conv": cache["conv"][j]})
+                parts["ssm"].append(st["ssm"])
+                parts["conv"].append(st["conv"])
+            elif kind == "moe":
+                y, _ = self._moe(p, x)
+            else:
+                j = len(parts["k"])
+                y, kc, vc = attn_decode(p, x, self.windows[i], self.thetas[i],
+                                        cache["k"][j], cache["v"][j])
+                parts["k"].append(kc)
+                parts["v"].append(vc)
+            x = x + y
+        new_cache: Params = {"pos": cache["pos"] + 1}
+        new_cache.update({name: jnp.stack(ps) for name, ps in parts.items()
+                          if ps})
+        return self._logits(params, x), new_cache
+
     # ------------------------------------------------------------- serving
     def prefill(self, params, batch, extra_slots: int = 0
                 ) -> Tuple[jnp.ndarray, Params]:
@@ -444,6 +555,8 @@ class Model:
         x = self.embed_inputs(params, batch)
         total = x.shape[1]
         positions = jnp.arange(total, dtype=jnp.int32)
+        if cfg.layer_pattern:
+            return self._pattern_prefill(params, x, positions, extra_slots)
         img_kv = self._image_kv(params, batch) if self.n_cross else None
 
         def body(x, xs):
@@ -468,7 +581,7 @@ class Model:
                     bp["attn"], x, positions, window, theta)
                 x = x + attn_out
                 if cfg.is_moe:
-                    y, _ = self._moe(bp, x)
+                    y, _ = self._moe(bp["moe"], x)
                     x = x + y
                 elif cfg.d_ff:
                     x = x + self._mlp(bp["mlp"], x)
@@ -505,7 +618,9 @@ class Model:
         ``max_len`` includes room for tokens to be decoded; meta tokens are
         added on top."""
         cfg, dt = self.cfg, self.dtype
-        L = cfg.num_layers
+        L = n_ssm = cfg.num_layers
+        if cfg.layer_pattern:
+            L, n_ssm = (cfg.layer_kinds().count(k) for k in ("global", "ssm"))
         total = max_len + cfg.num_meta_tokens
         cache: Params = {"pos": jnp.zeros((), jnp.int32)}
         if cfg.has_attention:
@@ -513,13 +628,12 @@ class Model:
                        cfg.resolved_head_dim)
             cache["k"] = jnp.zeros(kvshape, dt)
             cache["v"] = jnp.zeros(kvshape, dt)
-        if cfg.family in ("ssm", "hybrid"):
-            conv_ch = cfg.d_inner + 2 * cfg.ssm_state
-            cache["ssm"] = jnp.zeros((L, batch_size, cfg.ssm_heads,
+        if cfg.family in ("ssm", "hybrid", "interleaved"):
+            cache["ssm"] = jnp.zeros((n_ssm, batch_size, cfg.ssm_heads,
                                       cfg.ssm_headdim, cfg.ssm_state),
                                      jnp.float32)
-            cache["conv"] = jnp.zeros((L, batch_size, cfg.conv_width - 1,
-                                       conv_ch), dt)
+            cache["conv"] = jnp.zeros((n_ssm, batch_size, cfg.conv_width - 1,
+                                       cfg.ssm_conv_dim), dt)
         if self.n_cross:
             cache["xk"] = jnp.zeros((self.n_cross, batch_size,
                                      cfg.num_image_tokens, cfg.num_kv_heads,
@@ -555,6 +669,9 @@ class Model:
                 scale=self._scale(), chunk=self.opt.attn_chunk)
             return self._attn_out(bp, out), k_cache, v_cache
 
+        if cfg.layer_pattern:
+            return self._pattern_decode(params, x, cache, attn_decode)
+
         def body(x, xs):
             (bp, window, theta, is_cross, slot, kc, vc, ssm_st,
              conv_st) = xs
@@ -578,8 +695,7 @@ class Model:
                                                theta, kc, vc)
                 x = x + attn_out
                 if cfg.is_moe:
-                    y, _ = self._moe(bp, x,
-                                     group_size=x.shape[0] * x.shape[1])
+                    y, _ = self._moe(bp["moe"], x)
                     x = x + y
                 elif cfg.d_ff:
                     x = x + self._mlp(bp["mlp"], x)

@@ -180,10 +180,10 @@ def make_loss_fn(model: Model, step_cfg: StepConfig):
                                       batch["loss_mask"], ctx=model.ctx)
         total = loss
         metrics = {"ce_loss": loss, "accuracy": acc}
-        if aux:
+        if "moe_lb_loss" in aux:      # softmax routers only
             total = (total + step_cfg.moe_lb_weight * aux["moe_lb_loss"]
                      + step_cfg.moe_z_weight * aux["moe_z_loss"])
-            metrics.update(aux)
+        metrics.update(aux)
         metrics["loss"] = total
         return total, metrics
     return loss_fn

@@ -10,7 +10,7 @@ import pytest
 from repro.configs import ARCH_IDS, get_arch, reduced
 from repro.models import Model, ModelOptions, make_batch
 
-OPTS = ModelOptions(remat_policy="none", attn_chunk=16, moe_group_size=32)
+OPTS = ModelOptions(remat_policy="none", attn_chunk=16)
 
 
 @pytest.fixture(scope="module")
@@ -35,7 +35,8 @@ def test_arch_forward_shapes_and_finite(built, aid):
     assert logits.shape == (2, 32, cfg.vocab_size)
     assert bool(jnp.all(jnp.isfinite(logits)))
     if cfg.is_moe:
-        assert "moe_lb_loss" in aux
+        assert float(aux["moe_dropped_slots"]) == 0.0
+        assert ("moe_lb_loss" in aux) == (cfg.moe_router == "softmax")
 
 
 @pytest.mark.parametrize("aid", ARCH_IDS)
@@ -87,7 +88,7 @@ def test_arch_decode_step(built, aid):
 def test_pallas_path_parity(built, aid):
     cfg, model, params, batch = built(aid)
     m_p = Model(cfg, options=ModelOptions(remat_policy="none",
-                                          attn_chunk=16, moe_group_size=32,
+                                          attn_chunk=16,
                                           use_pallas=True))
     lx, _ = jax.jit(model.forward)(params, batch)
     lp, _ = jax.jit(m_p.forward)(params, batch)
