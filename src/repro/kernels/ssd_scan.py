@@ -4,8 +4,13 @@ The SSD algorithm's hot spot is the per-chunk quadratic part:
 ``y_diag = (C Bᵀ ∘ L) X`` plus the per-chunk state contribution
 ``S_c = (B ∘ decay)ᵀ X`` — three [Q,·]×[·,Q|P] matmuls per (batch, head,
 chunk).  This kernel runs them on the MXU with all chunk operands resident
-in VMEM; the cheap O(S) decay cumsums and the tiny inter-chunk recurrence
-stay in XLA (see repro.models.ssm.ssd_chunked for the reference pipeline).
+in VMEM; the decay cumsums and the tiny inter-chunk recurrence stay in XLA
+(see repro.models.ssm.ssd_chunked for the reference pipeline).  The
+cumsums are O(S) work but not cheap on TPU: XLA runs ``jnp.cumsum`` as a
+windowed reduction (``reduce-window``) of about one add per element of the
+chunk for each output, on the vector unit.  ``ssd_chunked`` computes them
+as one lower-triangular matmul a chunk on the MXU (``chunk_cumsum``); the
+wrapper here (``kernels.ops.ssd_op``) still uses ``jnp.cumsum``.
 
 Grid: (B, H, n_chunks); blocks: one chunk per program instance.  The
 wrapper lays the per-head operands out head-major ([B, H, S, P], and the

@@ -24,11 +24,25 @@ from repro.models.layers import dense_init, rms_norm, trunc_normal
 
 # ----------------------------------------------------------------- SSD core
 
-def segsum(a: jnp.ndarray) -> jnp.ndarray:
-    """Segment-sum: out[..., i, j] = sum_{k=j+1..i} a[..., k] (i>=j),
-    -inf elsewhere.  a: [..., Q] -> [..., Q, Q]."""
+def chunk_cumsum(a: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive cumulative sum over the last axis, as one matmul against a
+    [Q, Q] lower-triangular ones matrix.  a: [..., Q] f32 -> [..., Q] f32.
+
+    On TPU ``jnp.cumsum`` lowers to a windowed reduction, about Q adds an
+    output on the vector unit; this runs on the MXU, and its backward is
+    the transposed matmul.  HIGHEST keeps every bit of the f32 log-decays
+    (the default precision rounds them to bf16; the ones are exact)."""
     q = a.shape[-1]
-    cs = jnp.cumsum(a, axis=-1)
+    tri = jnp.tril(jnp.ones((q, q), jnp.float32))
+    return jnp.einsum("...k,ik->...i", a, tri,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def segsum(cs: jnp.ndarray) -> jnp.ndarray:
+    """Segment-sum from a chunk's cumulative sum ``cs`` of ``a``:
+    out[..., i, j] = cs[i] - cs[j] = sum_{k=j+1..i} a[..., k] (i>=j),
+    -inf elsewhere.  cs: [..., Q] -> [..., Q, Q]."""
+    q = cs.shape[-1]
     ss = cs[..., :, None] - cs[..., None, :]
     idx = jnp.arange(q)
     mask = idx[:, None] >= idx[None, :]
@@ -72,10 +86,10 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
     bc = chunked(b_mat.astype(jnp.float32), (n,))              # [B,C,Q,N]
     cc = chunked(c_mat.astype(jnp.float32), (n,))              # [B,C,Q,N]
 
-    a_cs = jnp.cumsum(ac, axis=-1)                             # [B,H,C,Q]
+    a_cs = chunk_cumsum(ac)                                    # [B,H,C,Q]
 
     # 1. intra-chunk ("diagonal block") — quadratic in Q, matmul-shaped
-    l_mat = jnp.exp(segsum(ac))                                # [B,H,C,Q,Q]
+    l_mat = jnp.exp(segsum(a_cs))                              # [B,H,C,Q,Q]
     y_diag = jnp.einsum("bcln,bcsn,bhcls,bcshp->bclhp",
                         cc, bc, l_mat, xc)
 

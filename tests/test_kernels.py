@@ -10,7 +10,7 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels.ops import flash_attention_op, ssd_op
 from repro.kernels.ref import ref_attention, ref_ssd_intra_chunk
 from repro.kernels.ssd_scan import ssd_intra_chunk
-from repro.models.ssm import ssd_chunked
+from repro.models.ssm import chunk_cumsum, ssd_chunked
 
 
 def _mk_qkv(key, b, sq, skv, hq, hkv, d, dtype):
@@ -131,6 +131,98 @@ def test_ssd_op_property(s, h, p, n, seed):
     y2, h2 = ssd_chunked(x, dt, a_log, bm, cm, 16)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-4)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=2e-4)
+
+
+def _log_decays(key, shape, a_log):
+    """Log-decays as large as the cells make them: -exp(a_log) x dt, with
+    a_log up to log 16 and dt = softplus(N(0, 1) + bias) per head."""
+    ks = jax.random.split(key, 2)
+    bias = jax.random.uniform(ks[0], (shape[-1],), minval=-1.0, maxval=1.0)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], shape) + bias)
+    return dt, -jnp.exp(a_log) * dt
+
+
+@pytest.mark.parametrize("q", [256, 128])
+def test_chunk_cumsum_is_the_f32_cumsum(q):
+    """The MXU form of the within-chunk cumulative sum is an f32 sum of
+    the f32 log-decays: within f32 summation's bound of a float64 sum and
+    of ``jnp.cumsum``."""
+    h = 48
+    _, a = _log_decays(jax.random.PRNGKey(q), (4, 8, q, h),
+                       jnp.log(jnp.linspace(1.0, 16.0, h)))
+    a = a.transpose(0, 3, 1, 2)                        # [B,H,C,Q]
+    got = np.asarray(chunk_cumsum(a))
+    want = np.asarray(jnp.cumsum(a, axis=-1))
+    exact = np.cumsum(np.asarray(a, np.float64), axis=-1)
+    assert got.dtype == np.float32
+    assert np.abs(exact).max() > 1000                  # large decays reached
+    # any f32 sum of Q terms of one sign is within Q·2^-24 of the total;
+    # a bf16 rounding of the decays would be off by about 2^-9
+    rtol = q * 2.0 ** -24
+    np.testing.assert_allclose(got, exact, rtol=rtol)
+    np.testing.assert_allclose(got, want, rtol=2 * rtol)
+
+
+def _ssd_recurrence(x, dt, a_log, bm, cm, h0):
+    """Step-by-step float64 SSD: h_t = exp(a_t) h_{t-1} + dt_t x_t B_t,
+    y_t = h_t C_t, with B/C [B,S,G,N] shared by H/G consecutive heads."""
+    hg = x.shape[2] // bm.shape[2]
+    bh, ch = (jnp.repeat(t, hg, axis=2) for t in (bm, cm))
+
+    def step(h, xs):
+        x_t, dt_t, b_t, c_t = xs
+        dec = jnp.exp(-jnp.exp(a_log) * dt_t)          # [B,H]
+        h = (h * dec[..., None, None]
+             + jnp.einsum("bhp,bhn->bhpn", x_t * dt_t[..., None], b_t))
+        return h, jnp.einsum("bhpn,bhn->bhp", h, c_t)
+
+    h_final, y = jax.lax.scan(
+        step, h0, tuple(t.swapaxes(0, 1) for t in (x, dt, bh, ch)))
+    return y.swapaxes(0, 1), h_final
+
+
+@pytest.mark.parametrize("groups", [1, 8])
+def test_ssd_chunked_matches_a_float64_recurrence(groups):
+    """Output, final state and the gradients in x, dt, a_log, B and C of
+    the chunked SSD (one group, and 8 B/C groups) against a float64
+    step-by-step recurrence; the sequence pads to a third chunk."""
+    b, s, h, p, n, chunk = 2, 80, 16, 8, 8, 32
+    ks = jax.random.split(jax.random.PRNGKey(groups), 6)
+    a_log = jnp.log(jnp.linspace(1.0, 16.0, h))
+    dt, _ = _log_decays(ks[0], (b, s, h), a_log)
+    x = jax.random.normal(ks[1], (b, s, h, p))
+    bm, cm = (jax.random.normal(k, (b, s, groups, n)) * 0.5
+              for k in ks[2:4])
+    h0 = jax.random.normal(ks[4], (b, h, p, n)) * 0.1
+    wy, wh = (jax.random.normal(k, shape) for k, shape in
+              zip(jax.random.split(ks[5]), ((b, s, h, p), (b, h, p, n))))
+
+    def loss(fn, x, dt, a_log, bm, cm):
+        y, h_final = fn(x, dt, a_log, bm, cm)
+        return jnp.sum(y * wy) + jnp.sum(h_final * wh), (y, h_final)
+
+    def prog(x, dt, a_log, bm, cm):
+        if groups == 1:
+            bm, cm = bm[:, :, 0], cm[:, :, 0]
+        return ssd_chunked(x, dt, a_log, bm, cm, chunk, h0)
+
+    args = (x, dt, a_log, bm, cm)
+    grad = jax.value_and_grad(loss, argnums=(1, 2, 3, 4, 5), has_aux=True)
+    (_, got), dgot = grad(prog, *args)
+    with jax.enable_x64(True):
+        args64 = [jnp.asarray(np.asarray(t), jnp.float64)
+                  for t in args + (h0, wy, wh)]
+        h0_64, wy, wh = args64[5:]
+        (_, want), dwant = grad(
+            lambda *a: _ssd_recurrence(*a, h0_64), *args64[:5])
+        want, dwant = jax.tree_util.tree_map(np.asarray, (want, dwant))
+    # a_log's gradient sums terms of both signs over every position, and
+    # dt's over each head's decays: f32 keeps fewer of their digits
+    rtol = {"dt": 2e-5, "a_log": 2e-4}
+    for name, g, w in zip(("y", "h_final", "x", "dt", "a_log", "B", "C"),
+                          got + dgot, want + dwant):
+        err = np.linalg.norm(np.asarray(g, np.float64) - w)
+        assert err <= rtol.get(name, 2e-6) * np.linalg.norm(w), name
 
 
 def test_kernels_refuse_backends_without_lowering(monkeypatch):

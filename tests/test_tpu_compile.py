@@ -4,7 +4,8 @@ The TPU compiler is installed even where no chip is, and it refuses what
 the Pallas interpreter accepts: blocks not aligned to the tiling, kernels
 that need too much fast memory, programs that do not fit the chip.  These
 tests compile the Pallas kernels at the real head layouts and the full-
-width mamba2-780m training step for one described v5e chip.
+width mamba2-780m training step for one described v5e chip, and check
+that the SSD's cumulative sums compile to no windowed reduction.
 """
 
 import os
@@ -19,6 +20,7 @@ from repro.core import hlo_cost
 from repro.data import SyntheticSource
 from repro.kernels.ops import flash_attention_op, ssd_op
 from repro.launch import train
+from repro.models.ssm import ssd_chunked
 from repro.train import StepConfig, make_train_step
 from repro.train.step import make_loss_fn
 
@@ -85,8 +87,10 @@ def test_ssd_compiles_at_mamba2_layout(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_mamba2_780m_train_step_fits_one_chip(one_chip):
-    """The launcher's step at published widths, seq 2048, batch 4."""
+@pytest.fixture(scope="module")
+def mamba2_step(one_chip):
+    """The launcher's step at published widths, seq 2048, batch 4:
+    (args, params' shapes, compiled step)."""
     args = train.parse_args(["--arch", "mamba2-780m", "--seq-len", "2048",
                              "--batch", "4", "--steps", "10"])
     cfg = train.build_config(args)
@@ -99,6 +103,11 @@ def test_mamba2_780m_train_step_fits_one_chip(one_chip):
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         _on(params, one_chip), _on(opt_state, one_chip), None,
         _on(batch, one_chip)).compile()
+    return args, params, compiled
+
+
+def test_mamba2_780m_train_step_fits_one_chip(mamba2_step):
+    args, params, compiled = mamba2_step
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -107,6 +116,36 @@ def test_mamba2_780m_train_step_fits_one_chip(one_chip):
     # 6·N·tokens is the floor; full remat recomputes the forward
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
     assert cost.flops > 6 * n_params * args.batch * args.seq_len
+
+
+def test_mamba2_780m_train_step_has_no_windowed_reduction(mamba2_step):
+    """The SSD's within-chunk cumulative sums run as matmuls: on TPU a
+    ``cumsum`` would lower to a ``reduce-window`` of about Q adds an
+    output, in the forward and again in the backward."""
+    assert "reduce-window" not in mamba2_step[2].as_text()
+
+
+def test_grouped_ssd_grad_has_no_windowed_reduction(one_chip):
+    """The grouped SSD's gradient at nemotron-3-nano-30b-a3b's layout
+    (64 heads in 8 B/C groups, chunk 128, seq 8192, batch 4)."""
+    cfg = get_arch("nemotron-3-nano-30b-a3b")
+    b, s, h, p = 4, 8192, cfg.ssm_heads, cfg.ssm_headdim
+    g, n = cfg.ssm_groups, cfg.ssm_state
+    assert (h, g, cfg.ssm_chunk) == (64, 8, 128)
+
+    def loss(x, dt, a_log, bm, cm):
+        return jnp.sum(ssd_chunked(x, dt, a_log, bm, cm,
+                                   cfg.ssm_chunk)[0].astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        _sds((b, s, h, p), jnp.bfloat16, one_chip),
+        _sds((b, s, h), jnp.float32, one_chip),
+        _sds((h,), jnp.float32, one_chip),
+        _sds((b, s, g, n), jnp.bfloat16, one_chip),
+        _sds((b, s, g, n), jnp.bfloat16, one_chip)).compile()
+    text = compiled.as_text()
+    assert "dot(" in text or "convolution(" in text
+    assert "reduce-window" not in text
 
 
 def test_attention_grad_compiles_without_pallas(one_chip):
