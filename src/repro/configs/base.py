@@ -10,6 +10,7 @@ ever lowered abstractly via the dry-run (ShapeDtypeStruct, no allocation).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -18,9 +19,9 @@ from typing import Dict, List, Optional, Tuple
 class ArchConfig:
     """A complete, family-generic model description.
 
-    The ``family`` tag selects the block structure in
-    ``repro.models.transformer``; unused fields are zero/None for
-    families that do not need them.
+    ``repro.models.transformer.layer_plan`` reads ``family`` and
+    ``layer_pattern`` once into each layer's mixer and FFN; unused fields
+    are zero/None for families that do not need them.
     """
 
     name: str
@@ -73,7 +74,6 @@ class ArchConfig:
     conv_bias: bool = False
 
     # --- hybrid (Hymba) ------------------------------------------------------
-    parallel_ssm: bool = False  # attention + SSM heads fused in one block
     num_meta_tokens: int = 0
 
     # --- modality frontends (stubs per task spec) ----------------------------
@@ -168,66 +168,19 @@ class ArchConfig:
         return [i for i in range(self.num_layers)
                 if (i + 1) % self.cross_attn_every == 0]
 
-    def _attn_params(self) -> int:
-        d, hd = self.d_model, self.resolved_head_dim
-        n_q, n_kv = self.num_heads, self.num_kv_heads
-        attn = d * n_q * hd + 2 * d * n_kv * hd + n_q * hd * d
-        if self.qk_norm:
-            attn += 2 * hd
-        return attn + d  # + input norm
-
-    def _ssm_params(self) -> int:
-        d, di, nh = self.d_model, self.d_inner, self.ssm_heads
-        conv = self.ssm_conv_dim
-        # in_proj -> (z, x, B, C, dt); conv (+ bias) on (x, B, C); out_proj
-        ssm = d * (di + conv + nh) + self.conv_width * conv
-        if self.conv_bias:
-            ssm += conv
-        ssm += nh * 3  # A_log, D, dt_bias
-        ssm += di      # gated norm
-        ssm += di * d  # out_proj
-        return ssm + d  # norm
-
     def _expert_params(self, ff: int) -> int:
         return (3 if self.expert_act == "swiglu" else 2) * self.d_model * ff
 
-    def _moe_params(self) -> int:
-        moe = self.d_model * self.num_experts  # router
-        if self.moe_router == "sigmoid":
-            moe += self.num_experts            # selection bias
-        moe += self.experts_held * self._expert_params(self.d_ff)
-        if self.shared_expert:
-            moe += self._expert_params(self.shared_ff)
-        return moe + self.d_model  # pre-FFN norm
-
     def param_count(self) -> int:
-        """Exact parameter count of the model as built by models/transformer.py."""
-        d, hd = self.d_model, self.resolved_head_dim
-        n_q, n_kv = self.num_heads, self.num_kv_heads
-        total = self.vocab_size * d  # embedding
-        if not self.tie_embeddings:
-            total += self.vocab_size * d  # lm head
-        total += d  # final norm
-        if self.layer_pattern:
-            per_kind = {"ssm": self._ssm_params(), "moe": self._moe_params(),
-                        "global": self._attn_params()}
-            return total + sum(per_kind[k] for k in self.layer_kinds())
-        per_layer = 0
-        if self.has_attention:
-            per_layer += self._attn_params()
-        if self.family in ("ssm", "hybrid"):
-            per_layer += self._ssm_params()
-        if self.is_moe:
-            per_layer += self._moe_params()
-        elif self.d_ff:
-            per_layer += 3 * d * self.d_ff + d  # gated MLP + norm
-        total += per_layer * self.num_layers
-        if self.cross_attn_every:
-            xattn = d * n_q * hd + 2 * d * n_kv * hd + n_q * hd * d + d
-            total += xattn * len(self.cross_attn_layers())
-        if self.num_meta_tokens:
-            total += self.num_meta_tokens * d
-        return total
+        """Number of parameters of the model as built: the elements of
+        ``repro.models.Model(self).init``'s tree, from its shapes alone,
+        so that no second description of a layer is kept here.  This is
+        the module's one reference up to the model, on purpose; the import
+        sits in the method because ``repro.models`` imports this module."""
+        import jax
+        from repro.models import Model
+        tree = jax.eval_shape(Model(self).init, jax.random.PRNGKey(0))
+        return sum(math.prod(x.shape) for x in jax.tree_util.tree_leaves(tree))
 
     def active_param_count(self) -> int:
         """Active parameters per token (MoE: only routed experts count)."""

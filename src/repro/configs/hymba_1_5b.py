@@ -25,7 +25,6 @@ CONFIG = register(ArchConfig(
     ssm_headdim=64,
     ssm_chunk=256,
     conv_width=4,
-    parallel_ssm=True,
     num_meta_tokens=128,
     source="arXiv:2411.13676; hf",
 ))

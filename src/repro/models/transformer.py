@@ -1,42 +1,46 @@
-"""The family-generic model: one scanned layer stack covering the assigned
-architectures (dense / MoE / SSM / hybrid / audio / VLM), and for an
-``interleaved`` model one stack per kind of layer, walked in the order of
-its ``layer_pattern``.
+"""The model: one description of each layer, walked in train, prefill and
+decode alike.
 
-Key structural decisions (see DESIGN.md §5):
-
-* **scan over stacked layer params** — per-layer weights carry a leading
-  ``[L]`` dim and run under ``jax.lax.scan``, keeping HLO size and compile
-  time O(1) in depth.  Per-layer *statics* that differ inside a stack
-  (gemma local/global window, per-layer rope theta) are passed as traced
-  scan inputs, so one traced body serves every layer.
-* **caches as scan xs/ys** — KV/SSM state is stacked ``[L, ...]`` and
-  flows through the scan as per-layer slices, giving natural donation.
-* **VLM grouping** — cross-attention blocks every k layers are handled by
-  an outer scan over groups (inner scan over k self layers + one gated
-  cross block), so cross params exist only where they are used.
-* **interleaved layers** — a model whose layers differ in kind (Mamba-2
-  mixer ``M``, expert layer ``E``, attention ``*``) keeps one parameter
-  stack per kind and walks its pattern layer by layer, each block under
-  the remat policy; its serving cache holds KV for the attention layers
-  and conv/SSM state for the Mamba layers, each stacked by its own kind.
-* **remat** — each block body can be wrapped in ``jax.checkpoint`` with a
-  selectable policy (a §Perf lever).
+* **the layer plan** — every layer is a residual mixer followed by a
+  residual FFN.  ``layer_plan`` reads the config once and names each
+  layer's pair: attention and an MLP (dense, audio, VLM) or MoE (moe); an
+  SSM and no FFN (ssm); hymba's fused attention ‖ SSM and an MLP
+  (hybrid); and for an ``interleaved`` model one pair a character of its
+  ``layer_pattern``: ``M`` an SSM, ``E`` MoE alone, ``*`` attention alone.
+  Parameters are held in one stack per part (``blocks/attn``,
+  ``blocks/ssm``, ``blocks/fuse``, ``blocks/mlp``, ``blocks/moe``), each as
+  deep as the layers that use it; the serving cache likewise (KV for the
+  attention layers, conv/SSM state for the SSM layers).  A new kind of
+  layer is one row of the plan plus its mixer or FFN.
+* **one layer function** — ``_layer`` applies one layer in every mode.
+  The modes differ only in the state a mixer takes and gives back: train
+  none, prefill gives back KV or conv/SSM state, decode takes the layer's
+  cache slice and gives back the updated slice.
+* **one walker** — ``_run_layers``.  A plan that repeats one layer runs
+  under ``jax.lax.scan`` over the stacked parameters, keeping HLO size and
+  compile time O(1) in depth; per-layer statics that differ inside a
+  stack (local/global window, rope theta) are traced scan inputs and the
+  caches flow through as per-layer slices.  A VLM's cross-attention block
+  every k layers is applied by an outer scan over groups of k layers, so
+  cross params exist only where they are used.  Any other plan is walked
+  layer by layer, each layer taking the next slice of its parts' stacks.
+* **remat** — in train each layer (and a VLM's group) runs under
+  ``jax.checkpoint`` with a selectable policy (a §Perf lever).
 * **named scopes** — the training step's work carries stable
   ``jax.named_scope`` names, so a device trace can be read by layer:
-  ``model.embed``, ``model.layers`` (the layer scan), ``model.block`` (a
-  block's norms, residual adds and hybrid fuse), ``model.ssm``,
-  ``model.attention``, ``model.mlp``, ``model.moe``, ``model.loss`` (final norm, logits,
-  cross entropy; ``train/step.py``) and ``optim.update``
+  ``model.embed``, ``model.layers`` (the walk), ``model.block`` (a
+  layer's norms, residual adds and hybrid fuse), ``model.ssm``,
+  ``model.attention``, ``model.mlp``, ``model.moe``, ``model.loss`` (final
+  norm, logits, cross entropy; ``train/step.py``) and ``optim.update``
   (``optim/optimizer.py``).  Backward ops carry their forward's scope
   as ``transpose(jvp(<scope>))``, custom-VJP backward rules included.
 """
 
 from __future__ import annotations
 
-import math
+import collections
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +60,22 @@ REMAT_POLICIES = {
     "dots": jax.checkpoint_policies.checkpoint_dots,
     "dots_no_batch": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
 }
+
+# the parameter stacks each mixer and FFN uses, and the cache each stack
+# keeps per layer
+STACKS = {"attn": ("attn",), "ssm": ("ssm",), "hybrid": ("attn", "ssm", "fuse"),
+          "mlp": ("mlp",), "moe": ("moe",), None: ()}
+CACHES = {"attn": ("k", "v"), "ssm": ("ssm", "conv")}
+
+
+def layer_plan(cfg: ArchConfig) -> List[Tuple[Optional[str], Optional[str]]]:
+    """(mixer, FFN) of each layer, in order; None where a layer has none."""
+    if cfg.layer_pattern:
+        rows = {"M": ("ssm", None), "E": (None, "moe"), "*": ("attn", None)}
+        return [rows[ch] for ch in cfg.layer_pattern]
+    mixer = cfg.family if cfg.family in ("ssm", "hybrid") else "attn"
+    ffn = "moe" if cfg.is_moe else "mlp" if cfg.d_ff else None
+    return [(mixer, ffn)] * cfg.num_layers
 
 
 @dataclass
@@ -85,18 +105,17 @@ class Model:
         self.thetas = jnp.array(
             [cfg.rope_theta if k == "local" else theta_g for k in kinds],
             jnp.float32)
-        # cross-attention bookkeeping (VLM)
-        cross_set = set(cfg.cross_attn_layers())
-        self.n_cross = len(cross_set)
-        slots, c = [], 0
-        for i in range(cfg.num_layers):
-            slots.append(c)
-            if i in cross_set:
-                c += 1
-        self.cross_flags = jnp.array(
-            [1 if i in cross_set else 0 for i in range(cfg.num_layers)],
-            jnp.int32)
-        self.cross_slots = jnp.array(slots, jnp.int32)
+        self.plan = layer_plan(cfg)
+        self.scanned = len(set(self.plan)) == 1
+        # each layer's slot in each stack it uses; the stacks' depths
+        taken: collections.Counter = collections.Counter()
+        self.slots = []
+        for mixer, ffn in self.plan:
+            names = STACKS[mixer] + STACKS[ffn]
+            self.slots.append({s: taken[s] for s in names})
+            taken.update(names)
+        self.stacks = dict(taken)
+        self.n_cross = len(cfg.cross_attn_layers())
 
     # ------------------------------------------------------------------ init
     def _init_attn(self, key, n_layers: int) -> Params:
@@ -134,9 +153,12 @@ class Model:
             p["post_norm_scale"] = jnp.zeros(L + (d,), dt)
         return p
 
-    def _init_stacked(self, init_one, key, n_layers: int) -> Params:
-        keys = jax.random.split(key, n_layers)
-        return jax.vmap(init_one)(keys)
+    def _init_fuse(self, _key, n_layers: int) -> Params:
+        d, dt = self.cfg.d_model, self.dtype
+        return {"attn_norm": jnp.zeros((n_layers, d), dt),
+                "ssm_norm": jnp.zeros((n_layers, d), dt),
+                "beta_attn": jnp.ones((n_layers,), jnp.float32),
+                "beta_ssm": jnp.ones((n_layers,), jnp.float32)}
 
     def init(self, key) -> Params:
         cfg, dt = self.cfg, self.dtype
@@ -150,34 +172,18 @@ class Model:
         if not cfg.tie_embeddings:
             params["lm_head"] = dense_init(kH, (cfg.d_model, cfg.vocab_size),
                                            dt)
-        blocks: Params = {}
-        L = cfg.num_layers
-        n_attn = n_ssm = n_moe = L
-        if cfg.layer_pattern:
-            kinds = cfg.layer_kinds()
-            n_attn, n_ssm, n_moe = (kinds.count(k)
-                                    for k in ("global", "ssm", "moe"))
-        if cfg.has_attention:
-            blocks["attn"] = self._init_attn(jax.random.fold_in(kB, 0),
-                                             n_attn)
-        if cfg.family in ("ssm", "hybrid", "interleaved"):
-            blocks["ssm"] = self._init_stacked(
-                lambda k: ssm_mod.init_ssm_params(k, cfg, dt),
-                jax.random.fold_in(kB, 1), n_ssm)
-        if cfg.family == "hybrid":
-            blocks["fuse"] = {
-                "attn_norm": jnp.zeros((L, cfg.d_model), dt),
-                "ssm_norm": jnp.zeros((L, cfg.d_model), dt),
-                "beta_attn": jnp.ones((L,), jnp.float32),
-                "beta_ssm": jnp.ones((L,), jnp.float32),
-            }
-        if cfg.is_moe:
-            blocks["moe"] = self._init_stacked(
-                lambda k: moe_mod.init_moe_params(k, cfg, dt),
-                jax.random.fold_in(kB, 2), n_moe)
-        elif cfg.d_ff:
-            blocks["mlp"] = self._init_mlp(jax.random.fold_in(kB, 3), L)
-        params["blocks"] = blocks
+
+        def stacked(init_one):
+            return lambda k, n: jax.vmap(init_one)(jax.random.split(k, n))
+        # each stack's key is folded in from its place in this order
+        inits = {"attn": self._init_attn,
+                 "ssm": stacked(lambda k: ssm_mod.init_ssm_params(k, cfg, dt)),
+                 "moe": stacked(lambda k: moe_mod.init_moe_params(k, cfg, dt)),
+                 "mlp": self._init_mlp, "fuse": self._init_fuse}
+        params["blocks"] = {
+            name: init(jax.random.fold_in(kB, i), self.stacks[name])
+            for i, (name, init) in enumerate(inits.items())
+            if self.stacks.get(name)}
         if self.n_cross:
             params["xblocks"] = {
                 "attn": self._init_attn(jax.random.fold_in(kX, 0),
@@ -219,12 +225,6 @@ class Model:
         v = self._constrain(v, "batch", None, "kv_heads", None)
         return q, k, v
 
-    def _attn_out(self, p, out):
-        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-        if "post_norm_scale" in p:
-            y = rms_norm(y, p["post_norm_scale"], self.cfg.norm_eps)
-        return y
-
     def _attend_seq(self, q, k, v, positions, window):
         """Sequence attention: Pallas flash kernel (TPU fast path) or the
         XLA online-softmax fallback.  ``window`` is a traced per-layer
@@ -253,16 +253,45 @@ class Model:
             cap=cfg.attn_logit_softcap, scale=self._scale(),
             chunk=self.opt.attn_chunk)
 
+    # ---------------------------------------------------- mixers and FFNs
     @jax.named_scope("model.attention")
-    def _self_attention(self, p, x, positions, window, theta):
-        """Pre-norm self attention over the fresh sequence (train/prefill).
-        Returns (block output, (k, v)) — k/v feed the prefill cache."""
+    def _attention(self, p, x, positions, window, theta, kv=None):
+        """Pre-norm self attention.  Without ``kv``, over the fresh
+        sequence (train/prefill); with the layer's cache slice ``kv``, one
+        token at ``positions[0]`` (decode).  Returns (output, {k, v}): the
+        fresh keys and values, or the updated cache slice."""
         cfg = self.cfg
         h = rms_norm(x, p["norm_scale"], cfg.norm_eps)
         h = self._constrain(h, "batch", "seq", "embed")
         q, k, v = self._qkv(p, h, positions, theta)
-        out = self._attend_seq(q, k, v, positions, window)
-        return self._attn_out(p, out), (k, v)
+        if kv is None:
+            out = self._attend_seq(q, k, v, positions, window)
+        else:
+            pos = positions[0]
+            k = jax.lax.dynamic_update_slice(
+                kv["k"], k.astype(kv["k"].dtype), (0, pos, 0, 0))
+            v = jax.lax.dynamic_update_slice(
+                kv["v"], v.astype(kv["v"].dtype), (0, pos, 0, 0))
+            kv_pos = jnp.arange(k.shape[1], dtype=jnp.int32)
+            kv_pos = jnp.where(kv_pos <= pos, kv_pos, -1)
+            out = attn_mod.attend(
+                q, k, v, positions, kv_pos, causal=True, window=window,
+                cap=cfg.attn_logit_softcap, scale=self._scale(),
+                chunk=self.opt.attn_chunk)
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+        if "post_norm_scale" in p:
+            y = rms_norm(y, p["post_norm_scale"], cfg.norm_eps)
+        return y, {"k": k, "v": v}
+
+    def _ssm(self, p, x, mode, state):
+        """The Mamba-2 mixer: (output, conv/SSM state), the state empty in
+        train."""
+        cfg = self.cfg
+        if mode == "decode":
+            return ssm_mod.apply_ssm_decode(p, cfg, x, state)
+        out = ssm_mod.apply_ssm_mixer(p, cfg, x, use_pallas=self.opt.use_pallas,
+                                      return_state=mode == "prefill")
+        return out if mode == "prefill" else (out, {})
 
     @jax.named_scope("model.mlp")
     def _mlp(self, p, x):
@@ -348,292 +377,170 @@ class Model:
                          cfg.final_logit_softcap)
         return self._constrain(logits, "batch", "seq", "vocab")
 
+    # ------------------------------------------------------------- layers
+    def _layer(self, kind, p, x, window, theta, positions, mode, state):
+        """One layer in ``mode`` (train | prefill | decode): ``x +
+        mixer(x)``, then ``x + ffn(x)``, each applying its own pre-norm.
+        ``p`` holds the layer's slice of each stack it uses and ``state``
+        its cache slice in decode.  Returns (x, MoE aux, the layer's new
+        cache slice: empty in train).  The walker calls it under
+        ``model.block``."""
+        mixer, ffn = kind
+        new: Params = {}
+        if mixer in ("attn", "hybrid"):
+            y, kv = self._attention(p["attn"], x, positions, window, theta,
+                                    state if mode == "decode" else None)
+            if mode != "train":
+                new.update(kv)
+        if mixer in ("ssm", "hybrid"):
+            y_ssm, st = self._ssm(p["ssm"], x, mode, state)
+            new.update(st)
+            y = (self._hybrid_mix(p["fuse"], y, y_ssm) if mixer == "hybrid"
+                 else y_ssm)
+        if mixer:
+            x = x + y
+        aux: Dict[str, jnp.ndarray] = {}
+        if ffn == "mlp":
+            x = x + self._mlp(p["mlp"], x)
+        elif ffn == "moe":
+            y, aux = self._moe(p["moe"], x)
+            x = x + y
+        return x, aux, new
+
+    def _run_layers(self, params, x, positions, mode, cache=None,
+                    img_kv=None):
+        """Every layer in order, in ``mode``; ``cache`` holds the layers'
+        stacked cache in decode.  Returns (x, MoE aux, the layers' new
+        cache stacked by name)."""
+        cfg = self.cfg
+        aux = moe_mod.aux_zeros(cfg) if cfg.is_moe else {}
+        remat = mode == "train" and self.opt.remat_policy != "none"
+
+        def ckpt(f):
+            if not remat:
+                return f
+            return jax.checkpoint(
+                f, policy=REMAT_POLICIES.get(self.opt.remat_policy),
+                prevent_cse=self.opt.remat_prevent_cse)
+
+        def combine(aux, a):
+            return moe_mod.combine_aux(aux, a) if a else aux
+
+        def constrain(x):
+            return self._constrain(x, "batch", "seq", "embed")
+
+        if not self.scanned:
+            parts: Dict[str, list] = collections.defaultdict(list)
+            with jax.named_scope("model.layers"):
+                for i, (kind, slots) in enumerate(zip(self.plan, self.slots)):
+                    p = {s: jax.tree_util.tree_map(
+                        lambda a, j=j: a[j], params["blocks"][s])
+                        for s, j in slots.items()}
+                    state = {n: cache[n][j] for s, j in slots.items()
+                             for n in CACHES.get(s, ())} if cache else {}
+
+                    def run(p, x, state, i=i, kind=kind):
+                        with jax.named_scope("model.block"):
+                            return self._layer(
+                                kind, p, x, self.windows[i], self.thetas[i],
+                                positions, mode, state)
+                    x, a, state = ckpt(run)(p, x, state)
+                    aux = combine(aux, a)
+                    x = constrain(x)
+                    for n, v in state.items():
+                        parts[n].append(v)
+            return x, aux, {n: jnp.stack(v) for n, v in parts.items()}
+
+        def body(carry, xs):
+            x, aux = carry
+            p, window, theta, state = xs
+            with jax.named_scope("model.block"):
+                x, a, state = self._layer(self.plan[0], p, x, window, theta,
+                                          positions, mode, state)
+            return (constrain(x), combine(aux, a)), state
+
+        xs = (params["blocks"], self.windows, self.thetas, cache or {})
+        if not self.n_cross:
+            with jax.named_scope("model.layers"):
+                (x, aux), states = jax.lax.scan(ckpt(body), (x, aux), xs)
+            return x, aux, states
+        # VLM: scan groups of ``every`` layers, each followed by its gated
+        # cross block.  Under remat both the layer and the group are
+        # checkpointed, so the backward of a group recomputes one layer at
+        # a time instead of holding the group's intermediates.
+        every = cfg.cross_attn_every
+        n_groups = cfg.num_layers // every
+        inner = ckpt(body)
+
+        def group_body(carry, xs):
+            layers, idx = xs
+            (x, aux), states = jax.lax.scan(inner, carry, layers)
+            x = self._cross_block(params["xblocks"], idx, x, img_kv)
+            return (constrain(x), aux), states
+
+        grouped = jax.tree_util.tree_map(
+            lambda a: a.reshape((n_groups, every) + a.shape[1:]), xs)
+        with jax.named_scope("model.layers"):
+            (x, aux), states = jax.lax.scan(
+                ckpt(group_body), (x, aux),
+                (grouped, jnp.arange(n_groups, dtype=jnp.int32)))
+        return x, aux, jax.tree_util.tree_map(
+            lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), states)
+
+    # ------------------------------------------------------- train forward
+    def forward(self, params, batch) -> Tuple[jnp.ndarray, Dict]:
+        """Full-sequence forward (training).  Returns (logits, aux)."""
+        x, aux = self.forward_hidden(params, batch)
+        with jax.named_scope("model.loss"):
+            return self._logits(params, x), aux
+
     def forward_hidden(self, params, batch) -> Tuple[jnp.ndarray, Dict]:
         """Training forward up to (but excluding) the unembedding.
         Used by the chunked cross-entropy path (train/step.py), which
         never materializes the full [B, S, V] logits."""
-        return self._forward_trunk(params, batch)
-
-    # ------------------------------------------------------- train forward
-    @jax.named_scope("model.block")
-    def _block_train(self, bp, x, window, theta, positions, aux):
-        cfg = self.cfg
-        if cfg.family == "ssm":
-            return x + ssm_mod.apply_ssm_mixer(bp["ssm"], cfg, x, use_pallas=self.opt.use_pallas), aux
-        if cfg.family == "hybrid":
-            attn_out, _ = self._self_attention(bp["attn"], x, positions,
-                                               window, theta)
-            ssm_out = ssm_mod.apply_ssm_mixer(bp["ssm"], cfg, x, use_pallas=self.opt.use_pallas)
-            x = x + self._hybrid_mix(bp["fuse"], attn_out, ssm_out)
-            return x + self._mlp(bp["mlp"], x), aux
-        attn_out, _ = self._self_attention(bp["attn"], x, positions,
-                                           window, theta)
-        x = x + attn_out
-        if cfg.is_moe:
-            y, a = self._moe(bp["moe"], x)
-            x = x + y
-            aux = moe_mod.combine_aux(aux, a)
-        elif cfg.d_ff:
-            x = x + self._mlp(bp["mlp"], x)
-        return x, aux
-
-    def forward(self, params, batch) -> Tuple[jnp.ndarray, Dict]:
-        """Full-sequence forward (training).  Returns (logits, aux)."""
-        x, aux = self._forward_trunk(params, batch)
-        with jax.named_scope("model.loss"):
-            return self._logits(params, x), aux
-
-    def _forward_trunk(self, params, batch) -> Tuple[jnp.ndarray, Dict]:
-        cfg = self.cfg
         x = self.embed_inputs(params, batch)
-        seq = x.shape[1]
-        positions = jnp.arange(seq, dtype=jnp.int32)
-        aux0 = moe_mod.aux_zeros(cfg) if cfg.is_moe else {}
-        policy = REMAT_POLICIES.get(self.opt.remat_policy)
-        remat = self.opt.remat_policy != "none"
-        if cfg.layer_pattern:
-            return self._pattern_trunk(params, x, positions, aux0,
-                                       policy if remat else False)
-
-        def body(carry, xs):
-            x, aux = carry
-            bp, window, theta = xs
-            x, aux = self._block_train(bp, x, window, theta, positions, aux)
-            x = self._constrain(x, "batch", "seq", "embed")
-            return (x, aux), None
-
-        if self.n_cross:
-            img_kv = self._image_kv(params, batch)
-            every = cfg.cross_attn_every
-            n_groups = cfg.num_layers // every
-            grouped = jax.tree_util.tree_map(
-                lambda a: a.reshape((n_groups, every) + a.shape[1:]),
-                params["blocks"])
-            windows = self.windows.reshape(n_groups, every)
-            thetas = self.thetas.reshape(n_groups, every)
-
-            # nested remat: inner per-layer body AND the outer group are
-            # checkpointed, so bwd of a group recomputes one layer at a
-            # time instead of holding 5 layers of intermediates.
-            inner = (jax.checkpoint(body, policy=policy,
-                                   prevent_cse=self.opt.remat_prevent_cse)
-                     if remat else body)
-
-            def group_body(carry, xs):
-                bp, window, theta, idx = xs
-                (x, aux), _ = jax.lax.scan(inner, carry,
-                                           (bp, window, theta))
-                x = self._cross_block(params["xblocks"], idx, x, img_kv)
-                x = self._constrain(x, "batch", "seq", "embed")
-                return (x, aux), None
-
-            if remat:
-                group_body = jax.checkpoint(group_body, policy=policy,
-                                            prevent_cse=self.opt.remat_prevent_cse)
-            with jax.named_scope("model.layers"):
-                (x, aux), _ = jax.lax.scan(
-                    group_body, (x, aux0),
-                    (grouped, windows, thetas,
-                     jnp.arange(n_groups, dtype=jnp.int32)))
-        else:
-            scanned = (jax.checkpoint(body, policy=policy,
-                                   prevent_cse=self.opt.remat_prevent_cse)
-                       if remat else body)
-            with jax.named_scope("model.layers"):
-                (x, aux), _ = jax.lax.scan(scanned, (x, aux0),
-                                           (params["blocks"], self.windows,
-                                            self.thetas))
-        if cfg.num_meta_tokens:
-            x = x[:, cfg.num_meta_tokens:]
+        positions = jnp.arange(x.shape[1], dtype=jnp.int32)
+        img_kv = self._image_kv(params, batch) if self.n_cross else None
+        x, aux, _ = self._run_layers(params, x, positions, "train",
+                                     img_kv=img_kv)
+        if self.cfg.num_meta_tokens:
+            x = x[:, self.cfg.num_meta_tokens:]
         return x, aux
-
-    # --------------------------------------------------- interleaved layers
-    def _pattern_layers(self, params):
-        """(layer index, kind, its parameters) of each layer of an
-        interleaved model, in pattern order: layer ``i`` of a kind takes
-        the next slice of that kind's stack."""
-        stacks = {"ssm": "ssm", "moe": "moe", "global": "attn"}
-        taken: Dict[str, int] = {}
-        for i, kind in enumerate(self.cfg.layer_kinds()):
-            j = taken[kind] = taken.get(kind, -1) + 1
-            yield i, kind, jax.tree_util.tree_map(
-                lambda a: a[j], params["blocks"][stacks[kind]])
-
-    def _pattern_trunk(self, params, x, positions, aux, policy):
-        """Training forward of an interleaved model: each layer is
-        ``x + mixer(norm(x))`` (each mixer applies its own pre-norm),
-        checkpointed under ``policy`` unless it is False."""
-        cfg = self.cfg
-
-        def block(kind, i):
-            @jax.named_scope("model.block")
-            def run(p, x):
-                if kind == "ssm":
-                    return x + ssm_mod.apply_ssm_mixer(
-                        p, cfg, x, use_pallas=self.opt.use_pallas), {}
-                if kind == "moe":
-                    y, a = self._moe(p, x)
-                    return x + y, a
-                y, _ = self._self_attention(p, x, positions, self.windows[i],
-                                            self.thetas[i])
-                return x + y, {}
-            if policy is False:
-                return run
-            return jax.checkpoint(run, policy=policy,
-                                  prevent_cse=self.opt.remat_prevent_cse)
-
-        with jax.named_scope("model.layers"):
-            for i, kind, p in self._pattern_layers(params):
-                x, a = block(kind, i)(p, x)
-                if a:
-                    aux = moe_mod.combine_aux(aux, a)
-                x = self._constrain(x, "batch", "seq", "embed")
-        if cfg.num_meta_tokens:
-            x = x[:, cfg.num_meta_tokens:]
-        return x, aux
-
-    def _pattern_prefill(self, params, x, positions, extra_slots):
-        """Prefill of an interleaved model: KV of each attention layer and
-        conv/SSM state of each Mamba layer, each stacked by its kind."""
-        cfg = self.cfg
-        parts: Dict[str, list] = {"k": [], "v": [], "ssm": [], "conv": []}
-        for i, kind, p in self._pattern_layers(params):
-            if kind == "ssm":
-                y, st = ssm_mod.apply_ssm_mixer(
-                    p, cfg, x, use_pallas=self.opt.use_pallas,
-                    return_state=True)
-                parts["ssm"].append(st["ssm"])
-                parts["conv"].append(st["conv"])
-            elif kind == "moe":
-                y, _ = self._moe(p, x)
-            else:
-                y, (k, v) = self._self_attention(
-                    p, x, positions, self.windows[i], self.thetas[i])
-                if extra_slots:
-                    pad = ((0, 0), (0, extra_slots), (0, 0), (0, 0))
-                    k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-                parts["k"].append(k)
-                parts["v"].append(v)
-            x = self._constrain(x + y, "batch", "seq", "embed")
-        cache: Params = {"pos": jnp.asarray(x.shape[1], jnp.int32)}
-        cache.update({name: jnp.stack(ps) for name, ps in parts.items()
-                      if ps})
-        return self._logits(params, x[:, -1:]), cache
-
-    def _pattern_decode(self, params, x, cache, attn_decode):
-        """One-token decode of an interleaved model through its cache."""
-        cfg = self.cfg
-        parts: Dict[str, list] = {"k": [], "v": [], "ssm": [], "conv": []}
-        for i, kind, p in self._pattern_layers(params):
-            if kind == "ssm":
-                j = len(parts["ssm"])
-                y, st = ssm_mod.apply_ssm_decode(
-                    p, cfg, x, {"ssm": cache["ssm"][j],
-                                "conv": cache["conv"][j]})
-                parts["ssm"].append(st["ssm"])
-                parts["conv"].append(st["conv"])
-            elif kind == "moe":
-                y, _ = self._moe(p, x)
-            else:
-                j = len(parts["k"])
-                y, kc, vc = attn_decode(p, x, self.windows[i], self.thetas[i],
-                                        cache["k"][j], cache["v"][j])
-                parts["k"].append(kc)
-                parts["v"].append(vc)
-            x = x + y
-        new_cache: Params = {"pos": cache["pos"] + 1}
-        new_cache.update({name: jnp.stack(ps) for name, ps in parts.items()
-                          if ps})
-        return self._logits(params, x), new_cache
 
     # ------------------------------------------------------------- serving
     def prefill(self, params, batch, extra_slots: int = 0
                 ) -> Tuple[jnp.ndarray, Params]:
         """Process the full prompt.  Returns (last-position logits, cache).
         ``extra_slots`` pre-allocates room for subsequent decode steps."""
-        cfg = self.cfg
         x = self.embed_inputs(params, batch)
         total = x.shape[1]
-        positions = jnp.arange(total, dtype=jnp.int32)
-        if cfg.layer_pattern:
-            return self._pattern_prefill(params, x, positions, extra_slots)
         img_kv = self._image_kv(params, batch) if self.n_cross else None
-
-        def body(x, xs):
-            bp, window, theta, is_cross, slot = xs
-            cache_out = {}
-            if cfg.family == "ssm":
-                y, st = ssm_mod.apply_ssm_mixer(bp["ssm"], cfg, x, use_pallas=self.opt.use_pallas,
-                                                return_state=True)
-                x = x + y
-                cache_out.update(st)
-            elif cfg.family == "hybrid":
-                attn_out, (k, v) = self._self_attention(
-                    bp["attn"], x, positions, window, theta)
-                ssm_out, st = ssm_mod.apply_ssm_mixer(bp["ssm"], cfg, x, use_pallas=self.opt.use_pallas,
-                                                      return_state=True)
-                x = x + self._hybrid_mix(bp["fuse"], attn_out, ssm_out)
-                x = x + self._mlp(bp["mlp"], x)
-                cache_out.update(st)
-                cache_out["k"], cache_out["v"] = k, v
-            else:
-                attn_out, (k, v) = self._self_attention(
-                    bp["attn"], x, positions, window, theta)
-                x = x + attn_out
-                if cfg.is_moe:
-                    y, _ = self._moe(bp["moe"], x)
-                    x = x + y
-                elif cfg.d_ff:
-                    x = x + self._mlp(bp["mlp"], x)
-                cache_out["k"], cache_out["v"] = k, v
-            if self.n_cross:
-                x = jax.lax.cond(
-                    is_cross > 0,
-                    lambda x: self._cross_block(params["xblocks"], slot, x,
-                                                img_kv),
-                    lambda x: x, x)
-            x = self._constrain(x, "batch", "seq", "embed")
-            return x, cache_out
-
-        x, layer_caches = jax.lax.scan(
-            body, x, (params["blocks"], self.windows, self.thetas,
-                      self.cross_flags, self.cross_slots))
-        cache: Params = {"pos": jnp.asarray(total, jnp.int32)}
-        if cfg.has_attention:
-            k, v = layer_caches["k"], layer_caches["v"]
-            if extra_slots:
-                pad = ((0, 0), (0, 0), (0, extra_slots), (0, 0), (0, 0))
-                k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-            cache["k"], cache["v"] = k, v
-        if cfg.family in ("ssm", "hybrid"):
-            cache["ssm"] = layer_caches["ssm"]
-            cache["conv"] = layer_caches["conv"]
+        x, _, cache = self._run_layers(
+            params, x, jnp.arange(total, dtype=jnp.int32), "prefill",
+            img_kv=img_kv)
+        if extra_slots and "k" in cache:
+            pad = ((0, 0), (0, 0), (0, extra_slots), (0, 0), (0, 0))
+            cache["k"], cache["v"] = (jnp.pad(cache[n], pad) for n in "kv")
+        cache["pos"] = jnp.asarray(total, jnp.int32)
         if self.n_cross:
             cache["xk"], cache["xv"] = img_kv
-        logits = self._logits(params, x[:, -1:])
-        return logits, cache
+        return self._logits(params, x[:, -1:]), cache
 
     def init_cache(self, batch_size: int, max_len: int) -> Params:
         """Allocate an empty decode cache (for cost analysis / cold decode).
         ``max_len`` includes room for tokens to be decoded; meta tokens are
         added on top."""
         cfg, dt = self.cfg, self.dtype
-        L = n_ssm = cfg.num_layers
-        if cfg.layer_pattern:
-            L, n_ssm = (cfg.layer_kinds().count(k) for k in ("global", "ssm"))
-        total = max_len + cfg.num_meta_tokens
         cache: Params = {"pos": jnp.zeros((), jnp.int32)}
-        if cfg.has_attention:
-            kvshape = (L, batch_size, total, cfg.num_kv_heads,
+        if self.stacks.get("attn"):
+            kvshape = (self.stacks["attn"], batch_size,
+                       max_len + cfg.num_meta_tokens, cfg.num_kv_heads,
                        cfg.resolved_head_dim)
             cache["k"] = jnp.zeros(kvshape, dt)
             cache["v"] = jnp.zeros(kvshape, dt)
-        if cfg.family in ("ssm", "hybrid", "interleaved"):
-            cache["ssm"] = jnp.zeros((n_ssm, batch_size, cfg.ssm_heads,
-                                      cfg.ssm_headdim, cfg.ssm_state),
-                                     jnp.float32)
-            cache["conv"] = jnp.zeros((n_ssm, batch_size, cfg.conv_width - 1,
-                                       cfg.ssm_conv_dim), dt)
+        if self.stacks.get("ssm"):
+            for n, a in ssm_mod.init_ssm_state(cfg, batch_size, dt).items():
+                cache[n] = jnp.zeros((self.stacks["ssm"],) + a.shape, a.dtype)
         if self.n_cross:
             cache["xk"] = jnp.zeros((self.n_cross, batch_size,
                                      cfg.num_image_tokens, cfg.num_kv_heads,
@@ -651,77 +558,12 @@ class Model:
         else:
             x = embed_lookup(params["embed"]["table"], batch["tokens"],
                              scale_by_dim=cfg.scale_embed)
-        positions = pos[None]
-        max_total = cache["k"].shape[2] if cfg.has_attention else 0
-
-        def attn_decode(bp, x, window, theta, k_cache, v_cache):
-            h = rms_norm(x, bp["norm_scale"], cfg.norm_eps)
-            q, k_new, v_new = self._qkv(bp, h, positions, theta)
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k_new.astype(k_cache.dtype), (0, pos, 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v_new.astype(v_cache.dtype), (0, pos, 0, 0))
-            kv_pos = jnp.arange(max_total, dtype=jnp.int32)
-            kv_pos = jnp.where(kv_pos <= pos, kv_pos, -1)
-            out = attn_mod.attend(
-                q, k_cache, v_cache, positions, kv_pos, causal=True,
-                window=window, cap=cfg.attn_logit_softcap,
-                scale=self._scale(), chunk=self.opt.attn_chunk)
-            return self._attn_out(bp, out), k_cache, v_cache
-
-        if cfg.layer_pattern:
-            return self._pattern_decode(params, x, cache, attn_decode)
-
-        def body(x, xs):
-            (bp, window, theta, is_cross, slot, kc, vc, ssm_st,
-             conv_st) = xs
-            out_cache = {}
-            if cfg.family == "ssm":
-                y, st = ssm_mod.apply_ssm_decode(
-                    bp["ssm"], cfg, x, {"ssm": ssm_st, "conv": conv_st})
-                x = x + y
-                out_cache["ssm"], out_cache["conv"] = st["ssm"], st["conv"]
-            elif cfg.family == "hybrid":
-                attn_out, kc, vc = attn_decode(bp["attn"], x, window,
-                                               theta, kc, vc)
-                ssm_out, st = ssm_mod.apply_ssm_decode(
-                    bp["ssm"], cfg, x, {"ssm": ssm_st, "conv": conv_st})
-                x = x + self._hybrid_mix(bp["fuse"], attn_out, ssm_out)
-                x = x + self._mlp(bp["mlp"], x)
-                out_cache.update({"ssm": st["ssm"], "conv": st["conv"],
-                                  "k": kc, "v": vc})
-            else:
-                attn_out, kc, vc = attn_decode(bp["attn"], x, window,
-                                               theta, kc, vc)
-                x = x + attn_out
-                if cfg.is_moe:
-                    y, _ = self._moe(bp["moe"], x)
-                    x = x + y
-                elif cfg.d_ff:
-                    x = x + self._mlp(bp["mlp"], x)
-                out_cache["k"], out_cache["v"] = kc, vc
-            if self.n_cross:
-                x = jax.lax.cond(
-                    is_cross > 0,
-                    lambda x: self._cross_block(
-                        params["xblocks"], slot, x,
-                        (cache["xk"], cache["xv"])),
-                    lambda x: x, x)
-            return x, out_cache
-
-        L = cfg.num_layers
-        dummy = jnp.zeros((L, 1), self.dtype)
-        xs = (params["blocks"], self.windows, self.thetas,
-              self.cross_flags, self.cross_slots,
-              cache.get("k", dummy), cache.get("v", dummy),
-              cache.get("ssm", dummy), cache.get("conv", dummy))
-        x, layer_caches = jax.lax.scan(body, x, xs)
-        new_cache: Params = {"pos": pos + 1}
-        for key in ("k", "v", "ssm", "conv"):
-            if key in cache:
-                new_cache[key] = layer_caches[key]
-        for key in ("xk", "xv"):
-            if key in cache:
-                new_cache[key] = cache[key]
-        logits = self._logits(params, x)
-        return logits, new_cache
+        xkv = (cache["xk"], cache["xv"]) if self.n_cross else None
+        x, _, new_cache = self._run_layers(
+            params, x, pos[None], "decode",
+            {n: cache[n] for n in ("k", "v", "ssm", "conv") if n in cache},
+            xkv)
+        new_cache["pos"] = pos + 1
+        if self.n_cross:
+            new_cache["xk"], new_cache["xv"] = xkv
+        return self._logits(params, x), new_cache

@@ -95,6 +95,16 @@ def test_pallas_path_parity(built, aid):
     assert float(jnp.max(jnp.abs(lx - lp))) < 5e-2
 
 
+@pytest.mark.parametrize("aid", ARCH_IDS)
+def test_param_count_is_the_built_tree(aid):
+    """The published size's count is the tree ``init`` builds, leaf for
+    leaf: norms, gates and cross blocks included."""
+    cfg = get_arch(aid)
+    tree = jax.eval_shape(Model(cfg).init, jax.random.PRNGKey(0))
+    assert cfg.param_count() == sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(tree))
+
+
 def test_decode_sequence_matches_forward():
     """Greedy decode token-by-token must agree with teacher-forced
     forward logits (qwen3 reduced)."""

@@ -25,7 +25,8 @@ from repro.optim import AdamW, OptimizerConfig
 from repro.train import StepConfig, make_train_step
 
 SCOPES = ("model.embed", "model.layers", "model.block", "model.ssm",
-          "model.attention", "model.mlp", "model.loss", "optim.update")
+          "model.attention", "model.mlp", "model.moe", "model.loss",
+          "optim.update")
 _SCOPE = re.compile(r"(?<![\w.])(%s)(?![\w.])"
                     % "|".join(re.escape(s) for s in SCOPES))
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\S+\s+"
@@ -61,7 +62,9 @@ def step_text():
 
 @pytest.mark.parametrize("aid,layers", [
     ("mamba2-780m", {"model.ssm"}),
-    ("hymba-1.5b", {"model.ssm", "model.attention", "model.mlp"})])
+    ("hymba-1.5b", {"model.ssm", "model.attention", "model.mlp"}),
+    ("nemotron-3-nano-30b-a3b", {"model.ssm", "model.attention",
+                                 "model.moe"})])
 def test_every_matmul_of_the_train_step_is_in_a_scope(step_text, aid,
                                                       layers):
     scoped = collections.defaultdict(list)
